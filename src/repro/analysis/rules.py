@@ -228,7 +228,7 @@ def kernel_parity(ctx: ModuleCtx) -> None:
     if len(parts) < 2 or parts[-2] != "kernels":
         return
     stem = parts[-1][:-3]
-    if stem in ("ref", "ops", "compat", "__init__"):
+    if stem in ("ref", "ops", "__init__"):
         return
     if not any(qualname(c.func).endswith("pallas_call")
                for c in calls_in(ctx.tree)):

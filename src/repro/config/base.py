@@ -65,7 +65,9 @@ class ModelConfig:
     param_dtype: str = "bfloat16"
 
     # --- execution ---
-    attention_impl: str = "reference"   # reference | pallas | pallas_interpret
+    # reference | pallas | pallas_interpret; None = the platform's
+    # (kernels.ops.resolve_impl: Pallas on TPU, the reference elsewhere)
+    attention_impl: Optional[str] = None
     scan_layers: bool = True
     remat: bool = True
     logits_softcap: float = 0.0

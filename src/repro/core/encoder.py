@@ -17,7 +17,7 @@ from __future__ import annotations
 import functools
 import re
 import zlib
-from typing import List, Sequence
+from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -92,6 +92,16 @@ def _project(flat_ids: jax.Array, seg: jax.Array, table: jax.Array,
     return h / n
 
 
+def bucket(n: int, floor: int = 1, cap: Optional[int] = None) -> int:
+    """The shape bucket for ``n``: the smallest power of two at or above both
+    ``n`` and ``floor``, at most ``cap``. Padding a shape up to its bucket
+    keeps the set of jit compiles a long-lived server pays for bounded."""
+    b = floor
+    while b < n:
+        b *= 2
+    return b if cap is None else min(b, cap)
+
+
 class EncoderStats:
     def __init__(self):
         self.calls = 0          # model invocations (a batch = 1 call)
@@ -134,14 +144,10 @@ class HashingEncoder:
         n = len(texts)
         # pad batch rows AND the flat token stream to power-of-two buckets:
         # bounded jit-compile set across the system's lifetime
-        cap = 1
-        while cap < n:
-            cap *= 2
+        cap = bucket(n)
         id_lists = [_tokenize(t) for t in texts]
         ntok = sum(len(ids) for ids in id_lists)
-        cap_tok = 16
-        while cap_tok < ntok:
-            cap_tok *= 2
+        cap_tok = bucket(ntok, 16)
         flat = np.zeros(cap_tok, np.int32)
         seg = np.full(cap_tok, cap, np.int32)   # padding -> scratch segment
         pos = 0
@@ -160,7 +166,18 @@ class HashingEncoder:
 
 
 class ModelEncoder:
-    """Zoo-LM-backed encoder: trunk forward + masked mean-pool."""
+    """Zoo-LM-backed encoder: trunk forward + masked mean-pool.
+
+    Each forward takes at most ``MAX_ROWS`` texts, and its row count and
+    token width pad to power-of-two buckets (as HashingEncoder's do), so a
+    long-lived server compiles the trunk for a bounded set of shapes and the
+    flash kernel's block always divides the width. Padding rows carry an
+    empty mask and are dropped; padding tokens sit after each text, where a
+    causal trunk never lets them reach the text's own positions."""
+
+    # 256 rows x 128 tokens keeps a phi3-mini-width forward's activations to
+    # a few GB beside the weights and a serving KV cache on one 16 GB chip
+    MAX_ROWS = 256
 
     def __init__(self, cfg, params, tokenizer, max_len: int = 128):
         from repro.models import get_model  # lazy: avoids cycle
@@ -193,17 +210,24 @@ class ModelEncoder:
         return self._fwd(list(texts))
 
     def _fwd(self, texts: List[str]) -> np.ndarray:
+        if len(texts) > self.MAX_ROWS:
+            return np.concatenate(
+                [self._fwd(texts[i:i + self.MAX_ROWS])
+                 for i in range(0, len(texts), self.MAX_ROWS)], axis=0)
         ids = [self.tok.encode(t)[: self.max_len] for t in texts]
-        L = max(len(i) for i in ids)
-        toks = np.zeros((len(ids), L), np.int32)
-        mask = np.zeros((len(ids), L), np.float32)
+        n = len(ids)
+        rows = bucket(n, 8)
+        L = bucket(max(len(i) for i in ids), 16, self.max_len)
+        toks = np.zeros((rows, L), np.int32)
+        mask = np.zeros((rows, L), np.float32)
         for i, seq in enumerate(ids):
             toks[i, : len(seq)] = seq
             mask[i, : len(seq)] = 1.0
         self.stats.calls += 1
         self.stats.tokens += int(mask.sum())
-        self.stats.texts += len(texts)
-        return np.asarray(self._pooled(self.params, jnp.asarray(toks), jnp.asarray(mask)))
+        self.stats.texts += n
+        out = self._pooled(self.params, jnp.asarray(toks), jnp.asarray(mask))
+        return np.asarray(out)[:n]
 
     def encode_one(self, text: str) -> np.ndarray:
         return self.encode([text])[0]

@@ -28,10 +28,12 @@ from repro.obs import Observability, get_obs
 
 
 class Forest:
-    def __init__(self, config: MemForestConfig, kernel_impl: str = "reference",
+    def __init__(self, config: MemForestConfig,
+                 kernel_impl: Optional[str] = None,
                  obs: Optional[Observability] = None):
         self.config = config
-        self.kernel_impl = kernel_impl
+        # None: Pallas on TPU, the reference elsewhere (ops.resolve_impl)
+        self.kernel_impl = ops.resolve_impl(kernel_impl)
         self.obs = get_obs(obs)
         self.trees: Dict[str, TreeArena] = {}
         self._tree_order: List[str] = []          # tree_id -> scope_key

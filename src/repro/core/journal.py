@@ -386,7 +386,7 @@ class DurableMemForest:
     # -- recovery ----------------------------------------------------------
     @classmethod
     def open(cls, root_dir: str, *, config=None, encoder=None,
-             kernel_impl: str = "reference", fsync: bool = True,
+             kernel_impl: Optional[str] = None, fsync: bool = True,
              snapshot_every: int = 0, crash=None,
              keep_snapshots: int = 2,
              obs: Optional[Observability] = None) -> "DurableMemForest":

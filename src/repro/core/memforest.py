@@ -26,7 +26,7 @@ class MemForestSystem:
     name = "memforest"
 
     def __init__(self, config: Optional[MemForestConfig] = None, encoder=None,
-                 kernel_impl: str = "reference", *, eager: bool = False,
+                 kernel_impl: Optional[str] = None, *, eager: bool = False,
                  parallel_extraction: bool = True,
                  obs: Optional[Observability] = None):
         from repro.core.encoder import HashingEncoder

@@ -151,7 +151,7 @@ def read_doc(path: str) -> Dict[str, Any]:
 
 def forest_from_doc(doc: Dict[str, Any], config: Optional[MemForestConfig] = None,
                     *, rematerialize_derived: bool = False,
-                    kernel_impl: str = "reference") -> Forest:
+                    kernel_impl: Optional[str] = None) -> Forest:
     assert doc["version"] in (1, 2, FORMAT_VERSION), doc["version"]
     cfg = config or MemForestConfig(
         chunk_turns=doc["config"]["chunk_turns"],
@@ -259,7 +259,7 @@ def forest_from_doc(doc: Dict[str, Any], config: Optional[MemForestConfig] = Non
 
 def load_forest(path: str, config: Optional[MemForestConfig] = None,
                 *, rematerialize_derived: bool = False,
-                kernel_impl: str = "reference") -> Forest:
+                kernel_impl: Optional[str] = None) -> Forest:
     return forest_from_doc(read_doc(path), config,
                            rematerialize_derived=rematerialize_derived,
                            kernel_impl=kernel_impl)

@@ -158,7 +158,7 @@ class ResidencyManager:
 
     def __init__(self, root_dir: str, *, config: Optional[ResidencyConfig] = None,
                  mem_config: Optional[MemForestConfig] = None, encoder=None,
-                 kernel_impl: str = "reference", crash=None,
+                 kernel_impl: Optional[str] = None, crash=None,
                  auto_enforce: bool = True,
                  obs: Optional[Observability] = None):
         from repro.core.encoder import HashingEncoder

@@ -175,10 +175,16 @@ class Retriever:
             flat_idx = np.asarray(flat_idx)
         root_vals = root_idx = None
         if n_trees:
-            root_vals, root_idx = ops.topk_sim(
-                qd, root_dev, min(cfg.forest_recall_topk * 3, n_trees),
-                normalize=False, num_valid=n_trees, impl=self.forest.kernel_impl,
-            )
+            k_roots = min(cfg.forest_recall_topk * 3, n_trees)
+            if self.forest.mesh is not None:
+                # the root index is replicated over the mesh
+                root_vals, root_idx = shard_ops.replicated_topk_sim(
+                    qd, root_dev, k_roots, mesh=self.forest.mesh,
+                    num_valid=n_trees, impl=self.forest.kernel_impl)
+            else:
+                root_vals, root_idx = ops.topk_sim(
+                    qd, root_dev, k_roots, normalize=False,
+                    num_valid=n_trees, impl=self.forest.kernel_impl)
             root_vals = np.asarray(root_vals)
             root_idx = np.asarray(root_idx)
 

@@ -17,8 +17,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 DEFAULT_BLOCK_F = 64
 
@@ -58,7 +58,7 @@ def browse_scores(
         ],
         out_specs=pl.BlockSpec((block_f, K), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Fp, K), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -5,8 +5,9 @@ Decode is memory-bound: the kernel streams the KV cache through VMEM in
 sharing a KV head) stays resident. Grid: (batch, kv_heads, num_kv_blocks),
 KV innermost/sequential with fp32 online-softmax scratch.
 
-Variable cache lengths are handled with a per-sequence length input; slots at
-or beyond the length are masked. The cache layout is (B, S, Hkv, D) — the
+Variable cache lengths arrive by scalar prefetch (SMEM, one int32 per
+sequence); slots at or beyond the length are masked, and KV blocks wholly
+beyond it are skipped. The cache layout is (B, S, Hkv, D) — the
 same layout `models.transformer` maintains — transposed to (B, Hkv, S, D)
 outside the kernel so tiles are contiguous along the streamed axis.
 """
@@ -19,14 +20,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 DEFAULT_BLOCK_KV = 1024
 NEG_INF = -1e30
 
 
 def _decode_kernel(
-    len_ref,                  # (1, 1) int32
+    len_ref,                  # (B,) int32 scalar prefetch
     q_ref,                    # (1, 1, G, D)
     k_ref, v_ref,             # (1, 1, bk, D)
     o_ref,                    # (1, 1, G, D)
@@ -36,6 +35,7 @@ def _decode_kernel(
     num_kv_blocks: int,
     sm_scale: float,
 ):
+    b = pl.program_id(0)
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -44,7 +44,7 @@ def _decode_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    length = len_ref[0, 0]
+    length = len_ref[b]
     # Skip blocks entirely beyond the valid cache length.
     @pl.when(ik * block_kv < length)
     def _body():
@@ -92,7 +92,6 @@ def decode_attention(
     qg = q.reshape(B, Hkv, group, D)
     kt = k_cache.transpose(0, 2, 1, 3)  # (B, Hkv, S, D)
     vt = v_cache.transpose(0, 2, 1, 3)
-    len2d = lengths.reshape(B, 1).astype(jnp.int32)
 
     kernel = functools.partial(
         _decode_kernel,
@@ -102,23 +101,25 @@ def decode_attention(
     )
     out = pl.pallas_call(
         kernel,
-        grid=(B, Hkv, nkv),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ik: (b, 0)),
-            pl.BlockSpec((1, 1, group, D), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, ik: (b, h, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, D), lambda b, h, ik: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hkv, nkv),
+            in_specs=[
+                pl.BlockSpec((1, 1, group, D), lambda b, h, ik, ln: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, block_kv, D), lambda b, h, ik, ln: (b, h, ik, 0)),
+                pl.BlockSpec((1, 1, block_kv, D), lambda b, h, ik, ln: (b, h, ik, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, group, D), lambda b, h, ik, ln: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((group, D), jnp.float32),
+                pltpu.VMEM((group, 1), jnp.float32),
+                pltpu.VMEM((group, 1), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((group, D), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(len2d, qg, kt, vt)
+    )(lengths.astype(jnp.int32), qg, kt, vt)
     return out.reshape(B, Hq, D)

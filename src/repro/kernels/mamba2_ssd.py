@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 DEFAULT_CHUNK = 64
 
@@ -136,7 +135,7 @@ def mamba2_ssd(
             jax.ShapeDtypeStruct((B, H, P, N), state.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

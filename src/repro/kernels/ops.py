@@ -1,11 +1,16 @@
 """Jit'd dispatchers over kernel implementations.
 
 ``impl`` selects:
-  * ``reference``        — pure-jnp oracle (ref.py). XLA-fused; the CPU
-                           dry-run / default model path.
+  * ``reference``        — pure-jnp oracle (ref.py). XLA-fused; the path
+                           off the TPU.
   * ``pallas``           — the Pallas TPU kernel (TARGET hardware).
   * ``pallas_interpret`` — the same kernel body executed in interpret mode
                            (CPU correctness validation; used by tests).
+
+:func:`resolve_impl` makes the platform decision in one place: callers that
+leave ``kernel_impl`` / ``attention_impl`` unset get the Pallas kernels on
+TPU and the reference everywhere else. Interpret mode runs only where a
+caller names it.
 
 Every dispatcher here is single-device; the mesh-sharded twins (shard-local
 launch of the SAME kernels + cheap cross-device merges) live in
@@ -16,6 +21,7 @@ that module — the single-device path below stays byte-identical.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +41,24 @@ VALID_IMPLS = ("reference", "pallas", "pallas_interpret")
 def _check(impl: str) -> None:
     if impl not in VALID_IMPLS:
         raise ValueError(f"impl must be one of {VALID_IMPLS}, got {impl!r}")
+
+
+# Kernels that do not lower for TPU yet: rwkv6_scan's (1, K) bonus block and
+# mamba2_ssd's (1, 1) decay block break Mosaic's tiling rule. They run only
+# where a caller names a Pallas impl.
+NOT_ON_TPU = frozenset({"rwkv6_scan", "mamba2_ssd"})
+
+
+def resolve_impl(impl: Optional[str] = None,
+                 kernel: Optional[str] = None) -> str:
+    """``impl`` if named, else the platform's: ``"pallas"`` when JAX's
+    default backend is a TPU and ``kernel`` (a dispatcher's name) lowers
+    there, ``"reference"`` otherwise."""
+    if impl is None:
+        on_tpu = jax.default_backend() == "tpu" and kernel not in NOT_ON_TPU
+        impl = "pallas" if on_tpu else "reference"
+    _check(impl)
+    return impl
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "impl", "block_q", "block_kv"))

@@ -1,8 +1,10 @@
 """Pure-jnp oracles for every Pallas kernel.
 
 These are the ground truth used by the per-kernel allclose tests and by the
-models when ``attention_impl == "reference"`` (the CPU dry-run path). They are
-written for clarity and exactness, not speed.
+models when ``attention_impl`` resolves to ``"reference"`` (every platform
+but the TPU). They are written for clarity and exactness, not speed: the
+memory-index scores use f32 at HIGHEST precision, as the Pallas kernels do,
+so on a TPU the two agree to f32 rounding rather than to bf16 passes.
 """
 from __future__ import annotations
 
@@ -96,7 +98,7 @@ def topk_sim_ref(
     if normalize:
         qf = qf / (jnp.linalg.norm(qf, axis=-1, keepdims=True) + 1e-6)
         kf = kf / (jnp.linalg.norm(kf, axis=-1, keepdims=True) + 1e-6)
-    scores = qf @ kf.T  # (Q, N)
+    scores = jnp.matmul(qf, kf.T, precision=jax.lax.Precision.HIGHEST)  # (Q, N)
     if num_valid is not None:
         cols = jnp.arange(scores.shape[1])[None, :]
         scores = jnp.where(cols < num_valid, scores, -1e30)
@@ -122,7 +124,8 @@ def browse_scores_ref(
     child_mask: jax.Array,  # (F, K) — 1.0 for real child slots
 ):
     s = jnp.einsum(
-        "fkd,fd->fk", child_emb.astype(jnp.float32), q_emb.astype(jnp.float32)
+        "fkd,fd->fk", child_emb.astype(jnp.float32), q_emb.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
     )
     return s * child_mask.astype(jnp.float32)
 

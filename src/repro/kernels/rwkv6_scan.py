@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 DEFAULT_CHUNK = 64
 
@@ -142,7 +141,7 @@ def rwkv6_scan(
             jax.ShapeDtypeStruct((B, H, K, V), state.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -32,7 +32,9 @@ deterministic (score desc, row id asc) tie-break shared by every top-k
 path, mesh=None and any mesh size are exactly result-identical.
 
 All builders are cached per (mesh, static shape bucket) so the jit-compile
-set stays bounded; meshes are hashable and close over their devices.
+set stays bounded; meshes are hashable and close over their devices. The
+builders that may launch a Pallas kernel turn off shard_map's varying-axes
+check (``check_vma=False``): a ``pallas_call`` declares no varying axes.
 """
 from __future__ import annotations
 
@@ -42,7 +44,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -77,7 +78,7 @@ def _normalize(x):
 # ---------------------------------------------------------------------------
 @functools.lru_cache(maxsize=None)
 def _normalize_sharded(mesh: Mesh, axis: str):
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         _normalize, mesh=mesh,
         in_specs=P(axis, None), out_specs=P(axis, None)))
 
@@ -108,7 +109,7 @@ def upload_replicated(host: np.ndarray, mesh: Mesh):
 def _grow_sharded(mesh: Mesh, axis: str, add_per_shard: int):
     def body(a):
         return jnp.pad(a, ((0, add_per_shard), (0, 0)))
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(axis, None), out_specs=P(axis, None)))
 
 
@@ -132,7 +133,7 @@ def _scatter_sharded(mesh: Mesh, axis: str):
         li = jnp.where(mine, idx // S, a.shape[0])
         return a.at[li].set(_normalize(rows), mode="drop")
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None), P(None), P(None, None)),
         out_specs=P(axis, None)))
@@ -173,10 +174,10 @@ def _topk_sharded(mesh: Mesh, axis: str, k: int, k_local: int, impl: str):
         pool_i = jnp.moveaxis(ai, 0, 1).reshape(q.shape[0], S * k_local)
         return merge_topk(pool_v, pool_i, k)
 
-    fn = shard_map(body, mesh=mesh,
+    fn = jax.shard_map(body, mesh=mesh,
                    in_specs=(P(), P(None, None), P(axis, None)),
                    out_specs=(P(None, None), P(None, None)),
-                   check_rep=False)
+                   check_vma=False)
     return jax.jit(fn)
 
 
@@ -194,6 +195,26 @@ def sharded_topk_sim(queries, keys, k: int, *, mesh: Mesh, axis: str = "data",
     return _topk_sharded(mesh, axis, k, k_local, impl)(nv, queries, keys)
 
 
+@functools.lru_cache(maxsize=None)
+def _topk_replicated(mesh: Mesh, k: int, impl: str):
+    def body(nv, q, kk):
+        return _local_topk(q, kk, k, nv, impl)
+
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(), P(), P()),
+                                 out_specs=(P(), P()), check_vma=False))
+
+
+def replicated_topk_sim(queries, keys, k: int, *, mesh: Mesh,
+                        num_valid=None, impl: str = "reference"):
+    """Fused top-k over a key matrix replicated on every device of ``mesh``
+    (the root index). Every device scans its own copy inside ``shard_map``
+    — a Pallas kernel cannot be partitioned automatically — and the result
+    is the single-device ``topk_sim`` result, replicated."""
+    nv = jnp.asarray(keys.shape[0] if num_valid is None else num_valid,
+                     jnp.int32)
+    return _topk_replicated(mesh, k, impl)(nv, queries, keys)
+
+
 # ---------------------------------------------------------------------------
 # sharded flush / browse batches (pure data parallelism over the batch dim)
 # ---------------------------------------------------------------------------
@@ -205,10 +226,10 @@ def _tree_refresh_sharded(mesh: Mesh, axis: str, impl: str):
         return _tree_refresh(emb, mask,
                              interpret=(impl == "pallas_interpret"))
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None, None), P(axis, None)),
-        out_specs=P(axis, None)))
+        out_specs=P(axis, None), check_vma=False))
 
 
 def sharded_tree_refresh(child_emb, child_mask, *, mesh: Mesh,
@@ -227,10 +248,10 @@ def _browse_sharded(mesh: Mesh, axis: str, impl: str):
             return _ref.browse_scores_ref(emb, q, mask)
         return _browse(emb, q, mask, interpret=(impl == "pallas_interpret"))
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None, None), P(axis, None), P(axis, None)),
-        out_specs=P(axis, None)))
+        out_specs=P(axis, None), check_vma=False))
 
 
 def sharded_browse_scores(child_emb, q_emb, child_mask, *, mesh: Mesh,

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 DEFAULT_BLOCK_P = 8
 
@@ -60,7 +59,7 @@ def tree_refresh(
         ],
         out_specs=pl.BlockSpec((block_p, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Pp, D), child_emb.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

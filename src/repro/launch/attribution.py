@@ -71,7 +71,7 @@ def attribute(hlo_text: str, top: int = 15) -> List[Tuple[float, str, int, str, 
 
 def main() -> None:
     from repro.launch.dryrun import build_cell
-    from repro.launch.mesh import activate_mesh, make_mesh, make_production_mesh
+    from repro.launch.mesh import make_mesh, make_production_mesh
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -96,7 +96,7 @@ def main() -> None:
 
     fn, cell_args, in_sh = build_cell(cfg, SHAPES[args.shape], mesh, tcfg,
                                       kv_replicate=args.kv_replicate)
-    with activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=in_sh).lower(*cell_args).compile()
     for b, op, mult, name, shapes in attribute(compiled.as_text(), args.top):
         print(f"{b/1e9:8.1f}GB  {op:18s} x{mult:<5d} {shapes}  {name}")

@@ -30,7 +30,7 @@ from repro.configs import ARCHS, get_config
 from repro.configs.shapes import SHAPE_ORDER, SHAPES, shape_applicable
 from repro.launch import sharding as shd
 from repro.launch.hlo_analysis import collective_bytes
-from repro.launch.mesh import activate_mesh, make_production_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import roofline_terms
 from repro.models import get_model
 from repro.models.factory import input_specs
@@ -162,7 +162,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
             donate_args = (0,) if shape.is_train else (
                 (2,) if shape.kind == "decode" else ())
         t0 = time.time()
-        with activate_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, in_shardings=in_sh,
                               donate_argnums=donate_args).lower(*args)
             t_lower = time.time() - t0

@@ -10,21 +10,11 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-# jax.sharding.AxisType landed after the pinned JAX version; older
-# jax.make_mesh has no axis_types kwarg, and its default (auto) matches what
-# we want — so only pass the kwarg when the running JAX understands it.
-try:
-    from jax.sharding import AxisType as _AxisType
-except ImportError:
-    _AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    if _AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(_AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -38,19 +28,12 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
     return _make(shape, axes)
 
 
-def activate_mesh(mesh: Mesh):
-    """Compat for ``jax.set_mesh`` (newer JAX): on older versions the Mesh
-    object itself is the context manager that installs the thread-local mesh."""
-    fn = getattr(jax, "set_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    return mesh
-
-
 def make_data_mesh(devices: int = 0, axis: str = "data") -> Optional[Mesh]:
     """1-D serve mesh over the first ``devices`` local devices (0 = all).
-    Returns None when fewer than 2 devices are available/requested — callers
-    treat None as the single-device fast path (Forest.set_mesh(None)).
+    Returns None when that is one device — callers treat None as the
+    single-device fast path (Forest.set_mesh(None)). Asking for more devices
+    than are present raises: a sharded deployment must not silently run on
+    fewer chips than it was configured for.
 
     Host-simulated multi-device testing: set
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` BEFORE the first
@@ -58,7 +41,11 @@ def make_data_mesh(devices: int = 0, axis: str = "data") -> Optional[Mesh]:
     import numpy as np
 
     avail = jax.devices()
-    n = len(avail) if devices <= 0 else min(devices, len(avail))
+    if devices > len(avail):
+        raise ValueError(
+            f"make_data_mesh: {devices} devices requested, "
+            f"{len(avail)} present ({avail[0].platform})")
+    n = len(avail) if devices <= 0 else devices
     if n <= 1:
         return None
     return Mesh(np.asarray(avail[:n]), (axis,))

@@ -25,21 +25,8 @@ DATA_AXES: Tuple[str, ...] = ("pod", "data")
 MODEL_AXIS = "model"
 
 
-def get_abstract_mesh():
-    """Compat shim: ``jax.sharding.get_abstract_mesh`` only exists in newer
-    JAX. On older versions fall back to the thread-local physical mesh (set
-    by the ``with Mesh(...)`` context manager), which exposes the same
-    ``.empty`` / ``.axis_names`` / ``.shape`` surface the callers need."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    from jax._src import mesh as _mesh_lib
-
-    return _mesh_lib.thread_resources.env.physical_mesh
-
-
 def current_mesh_axes() -> Tuple[str, ...]:
-    am = get_abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     return () if am.empty else tuple(am.axis_names)
 
 

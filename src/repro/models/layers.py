@@ -16,13 +16,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
 from repro.kernels import ops, ref
-from repro.launch.sharding import DATA_AXES, MODEL_AXIS, constrain, get_abstract_mesh
-
-# jax.shard_map was promoted out of jax.experimental after the pinned version
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
+from repro.launch.sharding import DATA_AXES, MODEL_AXIS, constrain
 
 Params = Dict[str, jax.Array]
 
@@ -46,7 +40,7 @@ def heads_axis(num_heads: int):
     """`model` if the head count divides evenly over the mesh's model axis,
     else None (replicate — avoids involuntary SPMD remat on GQA kv heads
     narrower than the TP width)."""
-    am = get_abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     if am.empty or MODEL_AXIS not in am.axis_names:
         return None
     size = dict(am.shape)[MODEL_AXIS]
@@ -131,12 +125,11 @@ def attention_prefill(
     q = constrain(q, DATA_AXES, None, heads_axis(cfg.num_heads), None)
     k = constrain(k, DATA_AXES, None, kv_ax, None)
     v = constrain(v, DATA_AXES, None, kv_ax, None)
-    if cfg.attention_impl == "reference" and S > 1024 and causal:
+    impl = ops.resolve_impl(cfg.attention_impl)
+    if impl == "reference" and S > 1024 and causal:
         o = ref.blockwise_causal_attention(q, k, v)
-    elif cfg.attention_impl.startswith("pallas"):
-        o = ops.attention(q, k, v, causal=causal, impl=cfg.attention_impl)
     else:
-        o = ops.attention(q, k, v, causal=causal, impl="reference")
+        o = ops.attention(q, k, v, causal=causal, impl=impl)
     out = o.reshape(B, S, cfg.q_dim) @ p["wo"]
     out = constrain(out, DATA_AXES, None, None)
     if return_kv:
@@ -171,8 +164,8 @@ def attention_decode(
     v_cache = jax.vmap(upd)(v_cache, v, lengths)
     k_cache = constrain(k_cache, DATA_AXES, seq_ax, kv_ax, None)
     v_cache = constrain(v_cache, DATA_AXES, seq_ax, kv_ax, None)
-    impl = cfg.attention_impl if cfg.attention_impl.startswith("pallas") else "reference"
-    o = ops.decode_attention(q, k_cache, v_cache, lengths + 1, impl=impl)
+    o = ops.decode_attention(q, k_cache, v_cache, lengths + 1,
+                             impl=ops.resolve_impl(cfg.attention_impl))
     out = o.reshape(B, cfg.q_dim) @ p["wo"]
     return constrain(out, DATA_AXES, None), k_cache, v_cache
 
@@ -314,7 +307,7 @@ def moe_block(p: Params, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax
         frac = jnp.zeros((E,), jnp.float32).at[gi.reshape(-1)].add(1.0) / (probs.shape[0] * K)
         return gw, gi, E * jnp.sum(frac * me)
 
-    am = get_abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     names = () if am.empty else tuple(am.axis_names)
     if MODEL_AXIS in names and E % dict(am.shape)[MODEL_AXIS] == 0:
         tp = dict(am.shape)[MODEL_AXIS]
@@ -353,7 +346,7 @@ def moe_block(p: Params, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax
 
         pspec_x = P(dp_axes if dp_axes else None, None, None)
         pspec_w = P(MODEL_AXIS, fsdp_axes if fsdp_axes else None, None)
-        y, aux = _shard_map(
+        y, aux = jax.shard_map(
             local, mesh=am,
             in_specs=(pspec_x, pspec_w, pspec_w, pspec_w),
             out_specs=(pspec_x, P()),

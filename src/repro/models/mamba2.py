@@ -86,8 +86,9 @@ def mamba2_seq(p, x, cfg: ModelConfig, ssd_state, conv_state):
     C = xbc[..., Din + N:]
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])       # (B,T,H)
     A = -jnp.exp(p["A_log"])                                           # (H,)
-    if cfg.attention_impl.startswith("pallas"):
-        y, s_new = ops.mamba2_ssd(xs, dt, A, Bm, C, ssd_state, impl=cfg.attention_impl)
+    impl = ops.resolve_impl(cfg.attention_impl, kernel="mamba2_ssd")
+    if impl.startswith("pallas"):
+        y, s_new = ops.mamba2_ssd(xs, dt, A, Bm, C, ssd_state, impl=impl)
     else:
         y, s_new = ref.mamba2_ssd_chunked(xs, dt, A, Bm, C, ssd_state)
     y = y + xs * p["D_skip"].astype(y.dtype)[None, None, :, None]

@@ -101,8 +101,9 @@ def _time_mix_seq(p, x, cfg: ModelConfig, state, shift_in):
     r = constrain(r, DATA_AXES, None, MODEL_AXIS, None)
     k = constrain(k, DATA_AXES, None, MODEL_AXIS, None)
     v = constrain(v, DATA_AXES, None, MODEL_AXIS, None)
-    if cfg.attention_impl.startswith("pallas"):
-        wkv, s_new = ops.rwkv6_scan(r, k, v, w, p["u"], state, impl=cfg.attention_impl)
+    impl = ops.resolve_impl(cfg.attention_impl, kernel="rwkv6_scan")
+    if impl.startswith("pallas"):
+        wkv, s_new = ops.rwkv6_scan(r, k, v, w, p["u"], state, impl=impl)
     else:
         wkv, s_new = ref.rwkv6_chunked(r, k, v, w.astype(jnp.float32), p["u"], state)
     wkv = wkv.reshape(B, T, D)

@@ -153,7 +153,13 @@ def cache_specs(cfg: ModelConfig, batch: int, max_len: int):
 
 
 def prefill(params, cfg: ModelConfig, batch: Dict[str, jax.Array], max_len: int):
-    """Full-sequence forward; returns (last logits (B, V), cache)."""
+    """Full-sequence forward; returns (last logits (B, V), cache).
+
+    With ``batch["lengths"]`` (B,) the prompts are left-aligned and padded
+    after their ends: the logits are each row's at its last real token, and
+    the cache records the true lengths, so decode writes over the padding
+    and never attends to it (causal prefill never lets a prompt see it
+    either). Without it every row is taken to be S tokens long."""
     x = _embed_batch(params, cfg, batch)
     B, S, _ = x.shape
     positions = jnp.arange(S)[None, :]
@@ -180,8 +186,12 @@ def prefill(params, cfg: ModelConfig, batch: Dict[str, jax.Array], max_len: int)
         return x, kv
 
     x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
-    h = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _logits(params, cfg, h[:, -1])
+    lengths = batch.get("lengths")
+    if lengths is None:
+        lengths = jnp.full((B,), S, jnp.int32)
+    last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
+    h = L.rms_norm(last, params["final_norm"], cfg.norm_eps)
+    logits = _logits(params, cfg, h)
 
     pad = max_len - S
     if pad > 0:
@@ -190,7 +200,7 @@ def prefill(params, cfg: ModelConfig, batch: Dict[str, jax.Array], max_len: int)
     cache = {
         "k": ks.astype(jnp.dtype(cfg.dtype)),
         "v": vs.astype(jnp.dtype(cfg.dtype)),
-        "lengths": jnp.full((B,), S, jnp.int32),
+        "lengths": lengths.astype(jnp.int32),
     }
     return logits, cache
 

@@ -28,8 +28,9 @@ the memory system's serve path over a 1-D data mesh
 (``launch.mesh.make_data_mesh`` + ``MemForestSystem.set_mesh``): fact-index
 rows round-robin across devices with shard-local top-k + candidate merge,
 browse lanes and flush refresh batches data-parallel, roots replicated.
-Results are exactly identical to single-device serve (kernels/shard_ops.py);
-with <2 devices the config degrades to the mesh=None fast path.
+Results are exactly identical to single-device serve (kernels/shard_ops.py).
+A one-device mesh is the mesh=None fast path; asking for more devices than
+are present raises.
 
 Maintenance lane: when built with a ``maintenance`` plane
 (core/maintenance_plane.py), ingest drains stop flushing inline
@@ -66,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.encoder import bucket
 from repro.data.tokenizer import HashTokenizer
 from repro.models.factory import Model
 from repro.obs import Observability, get_obs
@@ -86,11 +88,12 @@ class PrefixCache:
     """Prefill reuse cache for shared prompt prefixes.
 
     Granularity: one entry per (prefix_key, padded admission signature) —
-    the prefill of a whole right-aligned token block. Prefill is a pure
-    function of the padded token matrix, so when an admission with the same
-    prefix_key reproduces the same block (the common serving pattern:
-    repeated instruction-prefix prompts landing in freed slots), the cached
-    (logits, KV) are reused and the prefill launch is skipped entirely.
+    the prefill of a whole padded token block. Prefill is a pure function
+    of the padded token matrix and its prompt lengths, so when an admission
+    with the same prefix_key reproduces the same block (the common serving
+    pattern: repeated instruction-prefix prompts landing in freed slots),
+    the cached (logits, KV) are reused and the prefill launch is skipped
+    entirely.
     Finer prefix-segment reuse (prefix KV + suffix-only prefill) needs a
     position-offset prefill in the model API — ROADMAP open item.
 
@@ -120,8 +123,8 @@ class PrefixCache:
 
 @dataclass(frozen=True)
 class ShardedServeConfig:
-    """Multi-device serve knobs. ``devices=0`` means all local devices;
-    anything that resolves to <2 devices falls back to single-device."""
+    """Multi-device serve knobs. ``devices=0`` means all local devices; one
+    device is the single-device path, more than are present raises."""
     devices: int = 0
     axis: str = "data"
 
@@ -209,7 +212,29 @@ class ServeEngine:
         self._prefill = jax.jit(
             lambda p, b: model.prefill(p, b, max_len)
         )
-        self._decode = jax.jit(model.decode)
+        # The live cache is donated to the step that replaces it, so a
+        # full-width KV cache is never held twice on device (at phi3-mini
+        # width, B=8 x 1024 tokens, one cache is 3.2 GB).
+        self._decode = jax.jit(model.decode, donate_argnums=(2,))
+        # The admission merge is a masked select, not a gather + scatter:
+        # with the old cache donated it writes in place and needs no
+        # slot-sized temporaries, and one compile serves any slot count.
+        num_layers = model.cfg.num_layers
+
+        def merge(old_cache, new_cache, take):
+            def rows(old, new):
+                if old.ndim >= 2 and old.shape[0] == num_layers \
+                        and old.shape[1] == max_batch:
+                    lead = (1, max_batch)
+                elif old.ndim >= 1 and old.shape[0] == max_batch:
+                    lead = (max_batch,)
+                else:
+                    return old
+                m = take.reshape(lead + (1,) * (old.ndim - len(lead)))
+                return jnp.where(m, new, old)
+            return jax.tree.map(rows, old_cache, new_cache)
+
+        self._merge = jax.jit(merge, donate_argnums=(0,))
 
     # ------------------------------------------------------------------
     # registry-backed legacy counters (attribute back-compat)
@@ -261,6 +286,15 @@ class ServeEngine:
     # ------------------------------------------------------------------
     def submit(self, prompt_tokens: List[int], max_new_tokens: int = 8,
                prefix_key: Optional[str] = None) -> int:
+        """Queue a decode request. Its prompt and the tokens it may decode
+        must fit the cache: a request that would run past ``max_len``
+        raises here instead of overwriting its own cache's last slot."""
+        if not prompt_tokens:
+            raise ValueError("empty prompt")
+        if len(prompt_tokens) + max_new_tokens > self.max_len:
+            raise ValueError(
+                f"prompt of {len(prompt_tokens)} tokens + max_new_tokens="
+                f"{max_new_tokens} exceeds max_len={self.max_len}")
         r = Request(self._next_id, list(prompt_tokens), max_new_tokens,
                     prefix_key, submitted_s=time.perf_counter())
         self._next_id += 1
@@ -398,19 +432,39 @@ class ServeEngine:
              else [0])
             for i in range(B)
         ]
-        L = max(max(len(p) for p in prompts), 2)
+        # Attention-only trunks prefill prompts left-aligned at a bucketed
+        # width with their true lengths: the padding sits after each prompt,
+        # where causal attention never lets the prompt see it and decode
+        # writes over it, so a padded prefill decodes the same tokens as an
+        # exact-width one and leaves every slot past the prompt to decoding.
+        # A power-of-two width (16 up to max_len) bounds the set of prefill
+        # compiles and lets the flash kernel's block divide it. Recurrent
+        # trunks would carry that padding into their state, so they prefill
+        # right-aligned at the exact longest-prompt width.
+        padded = self.model.cfg.family in ("dense", "moe", "vlm")
+        longest = max(len(p) for p in prompts)
+        L = bucket(longest, 16, self.max_len) if padded else max(longest, 2)
+        lengths = np.ones(B, np.int32)
         toks = np.zeros((B, L), np.int32)
         for i in admitted_slots:
             p = prompts[i]
-            toks[i, L - len(p):] = p          # right-align
+            lengths[i] = len(p)
+            if padded:
+                toks[i, :len(p)] = p          # left-align, pad after
+            else:
+                toks[i, L - len(p):] = p      # right-align
+        batch = {"tokens": jnp.asarray(toks)}
+        if padded:
+            batch["lengths"] = jnp.asarray(lengths)
         # prefill reuse: when every admitted request carries the same
         # prefix_key and this admission reproduces a cached padded token
         # block, the prefill launch is skipped (prefill is a pure function
-        # of the block). jax arrays are immutable and the cache merge below
-        # is functional, so reuse is aliasing-safe.
+        # of the block and its lengths). jax arrays are immutable and the
+        # cache merge below is functional, so reuse is aliasing-safe.
         pkeys = {self.active[i].prefix_key for i in admitted_slots}
         pkey = pkeys.pop() if len(pkeys) == 1 else None
-        sig = (tuple(admitted_slots), toks.tobytes()) if pkey is not None else None
+        sig = ((tuple(admitted_slots), toks.tobytes(), lengths.tobytes())
+               if pkey is not None else None)
         hit = self.prefix_cache.get(pkey, sig) if pkey is not None else None
         self._m_prefills.inc()
         if hit is not None:
@@ -419,26 +473,23 @@ class ServeEngine:
         else:
             with self.obs.span("engine.prefill", slots=len(admitted_slots),
                                width=int(L)):
-                logits, new_cache = self._prefill(
-                    self.params, {"tokens": jnp.asarray(toks)})
+                logits, new_cache = self._prefill(self.params, batch)
             if pkey is not None:
                 self.prefix_cache.put(pkey, sig, logits, new_cache)
 
         if self.cache is None:
-            self.cache = new_cache
+            # decode donates the live cache: never hand it a prefix-cache
+            # entry's buffers, which later hits must still be able to read
+            self.cache = (new_cache if pkey is None
+                          else jax.tree.map(jnp.copy, new_cache))
             self._last_logits = logits
         else:
-            slots = jnp.asarray(admitted_slots, jnp.int32)
-
-            def merge(old, new):
-                if old.ndim >= 2 and old.shape[0] == self.model.cfg.num_layers \
-                        and old.shape[1] == B:
-                    return old.at[:, slots].set(new[:, slots])
-                if old.ndim >= 1 and old.shape[0] == B:
-                    return old.at[slots].set(new[slots])
-                return old
-            self.cache = jax.tree.map(merge, self.cache, new_cache)
-            self._last_logits = self._last_logits.at[slots].set(logits[slots])
+            take = np.zeros(B, bool)
+            take[admitted_slots] = True
+            take = jnp.asarray(take)
+            self.cache = self._merge(self.cache, new_cache, take)
+            self._last_logits = jnp.where(take[:, None], logits,
+                                          self._last_logits)
         return [self.active[i] for i in admitted_slots]
 
     # ------------------------------------------------------------------

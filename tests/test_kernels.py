@@ -96,6 +96,64 @@ def test_topk_sim_num_valid(rng):
     assert np.array_equal(np.asarray(i1), np.asarray(i3))
 
 
+@pytest.mark.parametrize("block_kv", [8, 16, 512])
+def test_topk_sim_planted_ties_keep_index_order(block_kv):
+    """Keys repeat 5 distinct rows, so every score is held by ~8 keys spread
+    over several key tiles: the kernel must order them (score desc, key
+    index asc), exactly as the oracle's two-key sort does."""
+    from repro.kernels.topk_sim import topk_sim as topk_kernel
+
+    rng = np.random.default_rng(3)
+    base = rng.normal(size=(5, 16)).astype(np.float32)
+    group = rng.integers(0, 5, size=40)
+    keys = jnp.asarray(base[group])
+    q = jnp.asarray(rng.normal(size=(3, 16)), jnp.float32)
+    vals, idx = topk_kernel(q, keys, 12, block_kv=block_kv, interpret=True)
+    _, ridx = ops.topk_sim(q, keys, 12, impl="reference")
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(ridx))
+    bn = base / np.linalg.norm(base, axis=-1, keepdims=True)
+    qn = np.asarray(q) / np.linalg.norm(np.asarray(q), axis=-1, keepdims=True)
+    for r in range(3):
+        order = np.argsort(-(bn @ qn[r]), kind="stable")
+        want = [i for g in order for i in np.flatnonzero(group == g)][:12]
+        assert list(np.asarray(idx[r])) == want
+        assert np.all(np.diff(np.asarray(vals[r])) <= 0)
+
+
+def test_resolve_impl_platform_default():
+    """Unset kernel/attention implementations resolve by platform: the
+    reference off the TPU, so every CPU test keeps its path."""
+    from repro.config import MemForestConfig, ModelConfig
+    from repro.core.forest import Forest
+
+    assert jax.default_backend() == "cpu"
+    assert ops.resolve_impl() == "reference"
+    assert ops.resolve_impl(None) == "reference"
+    for impl in ops.VALID_IMPLS:
+        assert ops.resolve_impl(impl) == impl
+    with pytest.raises(ValueError):
+        ops.resolve_impl("mosaic")
+    assert Forest(MemForestConfig()).kernel_impl == "reference"
+    assert Forest(MemForestConfig(),
+                  kernel_impl="pallas_interpret").kernel_impl == "pallas_interpret"
+    cfg = ModelConfig(name="t", family="dense", num_layers=1, d_model=8,
+                      num_heads=2, num_kv_heads=2, d_ff=16, vocab_size=32)
+    assert cfg.attention_impl is None
+    assert ops.resolve_impl(cfg.attention_impl) == "reference"
+
+
+def test_resolve_impl_on_tpu_skips_kernels_that_do_not_lower(monkeypatch):
+    """On a TPU an unset implementation is Pallas, except for the kernels
+    that do not lower there yet; a named implementation always wins."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.resolve_impl() == "pallas"
+    assert ops.resolve_impl(kernel="topk_sim") == "pallas"
+    assert ops.NOT_ON_TPU == {"rwkv6_scan", "mamba2_ssd"}
+    for kernel in ops.NOT_ON_TPU:
+        assert ops.resolve_impl(kernel=kernel) == "reference"
+        assert ops.resolve_impl("pallas", kernel=kernel) == "pallas"
+
+
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("P,K,D", [(1, 2, 16), (10, 8, 32), (33, 16, 256)])
 def test_tree_refresh(rng, P, K, D):

@@ -74,6 +74,58 @@ def test_continuous_batching_preserves_active_decodes():
     assert out_solo == out_mixed
 
 
+def _greedy_exact_width(model, params, prompt, n, max_len):
+    """Greedy decode from an unpadded prefill of exactly ``prompt``."""
+    import jax.numpy as jnp
+
+    logits, cache = jax.jit(lambda p, b: model.prefill(p, b, max_len))(
+        params, {"tokens": jnp.asarray([prompt], jnp.int32)})
+    decode = jax.jit(model.decode)
+    out = []
+    for _ in range(n):
+        tok = int(jnp.argmax(logits[0]))
+        out.append(tok)
+        logits, cache = decode(params, {"tokens": jnp.asarray([tok], jnp.int32)},
+                               cache)
+    return out
+
+
+def test_bucketed_prefill_decodes_like_exact_width():
+    """A prompt one past half of max_len prefills at width max_len. Padding
+    must neither take the decode slots nor change a token: both the long
+    prompt and a short one admitted beside it decode exactly as they do from
+    exact-width prefills of their own."""
+    cfg = get_smoke_config("llama3_8b")
+    model = get_model(cfg)
+    params = model.init(jax.random.key(0))
+    max_len = 32
+    rng = np.random.default_rng(3)
+    long_prompt = [int(t) for t in rng.integers(3, 400, size=max_len // 2 + 1)]
+    short_prompt = [5, 6, 7]
+    n_long = max_len - len(long_prompt)            # every slot left
+    eng = ServeEngine(model, params, max_batch=2, max_len=max_len, eos_id=-1)
+    eng.submit(long_prompt, max_new_tokens=n_long)
+    eng.submit(short_prompt, max_new_tokens=8)
+    done = {tuple(r.prompt_tokens): r.out_tokens
+            for r in eng.run_until_drained()}
+    assert done[tuple(long_prompt)] == _greedy_exact_width(
+        model, params, long_prompt, n_long, max_len)
+    assert done[tuple(short_prompt)] == _greedy_exact_width(
+        model, params, short_prompt, 8, max_len)
+
+
+@pytest.mark.parametrize("prompt_len,max_new", [(0, 4), (30, 3), (33, 0)])
+def test_submit_rejects_requests_past_max_len(prompt_len, max_new):
+    """A request whose prompt plus decode budget overruns the cache raises
+    at submit instead of overwriting its own last cache slot."""
+    eng = ServeEngine(get_model(get_smoke_config("llama3_8b")), None,
+                      max_batch=2, max_len=32)
+    with pytest.raises(ValueError):
+        eng.submit(list(range(3, 3 + prompt_len)), max_new_tokens=max_new)
+    eng.submit(list(range(3, 5)), max_new_tokens=30)   # exactly fits
+    assert len(eng.queue) == 1
+
+
 def test_prefix_cache_reuses_prefill():
     """Re-admitting the same prefix-keyed prompt block must hit the cache,
     skip the prefill launch, and decode identically."""

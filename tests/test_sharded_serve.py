@@ -108,14 +108,15 @@ def test_grow_rows_preserves_existing():
 
 
 # ---------------------------------------------------------------------------
-# mesh plumbing on a single device (fast-path fallbacks)
+# mesh plumbing on a single device (fast path; no silent clipping)
 # ---------------------------------------------------------------------------
 def test_make_data_mesh_single_device_is_none():
     from repro.launch.mesh import make_data_mesh
 
     assert make_data_mesh() is None      # 1 visible device
     assert make_data_mesh(1) is None
-    assert make_data_mesh(4) is None     # capped at available
+    with pytest.raises(ValueError, match="4 devices requested, 1 present"):
+        make_data_mesh(4)                # never clipped to what is present
 
 
 def test_set_mesh_none_is_identity():
@@ -135,7 +136,8 @@ def test_set_mesh_none_is_identity():
 
 
 def test_sharded_serve_config_single_device_fallback():
-    """ShardedServeConfig on a 1-device host degrades to mesh=None serve."""
+    """ShardedServeConfig asking for more devices than the host has raises
+    instead of degrading to mesh=None serve; one device is the fast path."""
     from repro.config import MemForestConfig
     from repro.core.memforest import MemForestSystem
     from repro.serving.engine import ServeEngine, ShardedServeConfig
@@ -155,8 +157,12 @@ def test_sharded_serve_config_single_device_fallback():
             return jnp.zeros((B, 4)), cache
 
     mf = MemForestSystem(MemForestConfig())
+    with pytest.raises(ValueError, match="4 devices requested"):
+        ServeEngine(_NoModel(), None, memory=mf,
+                    sharded=ShardedServeConfig(devices=4))
+    assert mf.forest.mesh is None
     eng = ServeEngine(_NoModel(), None, memory=mf,
-                      sharded=ShardedServeConfig(devices=4))
+                      sharded=ShardedServeConfig(devices=1))
     assert eng.serve_mesh is None
     assert mf.forest.mesh is None
     assert eng.metrics()["serve_devices"] == 1

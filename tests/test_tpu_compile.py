@@ -1,0 +1,93 @@
+"""Compile the serve path's Pallas kernels for a TPU v5e chip, without one.
+
+The TPU compiler is installed even where no chip is attached. Each test
+compiles one kernel at the widths the served phi3-mini path uses (D=3072
+memory index, 32 heads of 96, prefill buckets, a 1024-slot decode cache)
+for one chip of a described ``v5e:2x2`` topology, and checks that the
+program holds the kernel (``tpu_custom_call``). Nothing runs: a compile
+that passes says nothing about results or times.
+
+The topology is described only inside the ``topo`` fixture, never while a
+module is imported, so that only the worker given this file loads the TPU
+library. Keep these tests in this one file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+
+D = 3072                     # phi3-mini d_model = MemForest embed_dim
+HEADS, HEAD_DIM = 32, 96     # phi3-mini attention (MHA)
+MAX_LEN = 1024               # served decode cache
+
+
+@pytest.fixture(scope="module")
+def topo():
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        from jax.experimental import topologies
+
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the persistent compilation cache off: a
+    compile for an absent device can be written but never read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
+
+
+@pytest.mark.parametrize("dim,n,k", [(D, 4096, 16), (D, 4096, 24),
+                                     (D, 300, 16), (256, 4096, 16)])
+def test_topk_sim_compiles(one_chip, dim, n, k):
+    _compile(lambda q, keys: ops.topk_sim(q, keys, k, normalize=False,
+                                          num_valid=n - 7, impl="pallas"),
+             one_chip, ((32, dim), F32), ((n, dim), F32))
+
+
+@pytest.mark.parametrize("f,k", [(64, 8), (8, 4)])
+def test_browse_scores_compiles(one_chip, f, k):
+    _compile(lambda e, q, m: ops.browse_scores(e, q, m, impl="pallas"),
+             one_chip, ((f, k, D), F32), ((f, D), F32), ((f, k), F32))
+
+
+@pytest.mark.parametrize("p", [16, 1])
+def test_tree_refresh_compiles(one_chip, p):
+    _compile(lambda e, m: ops.tree_refresh(e, m, impl="pallas"),
+             one_chip, ((p, 8, D), F32), ((p, 8), F32))
+
+
+@pytest.mark.parametrize("s", [16, 64, 128, 1024])
+def test_flash_attention_compiles(one_chip, s):
+    qkv = ((8, s, HEADS, HEAD_DIM), BF16)
+    _compile(lambda q, k, v: ops.attention(q, k, v, impl="pallas"),
+             one_chip, qkv, qkv, qkv)
+
+
+def test_decode_attention_compiles(one_chip):
+    cache = ((8, MAX_LEN, HEADS, HEAD_DIM), BF16)
+    _compile(lambda q, k, v, n: ops.decode_attention(q, k, v, n, impl="pallas"),
+             one_chip, ((8, HEADS, HEAD_DIM), BF16), cache, cache, ((8,), I32))
