@@ -11,6 +11,8 @@ the same code path a production deployment would use with Qwen3 (the paper's
 builder).
 
 Both count calls and tokens so benchmarks can report Table-2-style cost.
+ModelEncoder also opens an ``encoder.forward`` span per forward (children
+``encoder.tokenize`` and ``encoder.device``) carrying its padded shape.
 """
 from __future__ import annotations
 
@@ -22,6 +24,8 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.obs import Observability
 
 
 def _stable_hash(s: str) -> int:
@@ -173,7 +177,12 @@ class ModelEncoder:
     long-lived server compiles the trunk for a bounded set of shapes and the
     flash kernel's block always divides the width. Padding rows carry an
     empty mask and are dropped; padding tokens sit after each text, where a
-    causal trunk never lets them reach the text's own positions."""
+    causal trunk never lets them reach the text's own positions.
+
+    Each forward is an ``encoder.forward`` span (attributes ``texts``,
+    ``rows``, ``width``, ``tokens``: real, ``padded_tokens``: rows x
+    width) with children ``encoder.tokenize`` (host tokenizing and padding)
+    and ``encoder.device`` (dispatch to the host copy of the result)."""
 
     # 256 rows x 128 tokens keeps a phi3-mini-width forward's activations to
     # a few GB beside the weights and a serving KV cache on one 16 GB chip
@@ -190,6 +199,7 @@ class ModelEncoder:
         self.max_len = max_len
         self.dim = cfg.d_model
         self.stats = EncoderStats()
+        self.obs = Observability()
 
         def pooled(params, tokens, mask):
             x = params["embed"][tokens]
@@ -214,20 +224,27 @@ class ModelEncoder:
             return np.concatenate(
                 [self._fwd(texts[i:i + self.MAX_ROWS])
                  for i in range(0, len(texts), self.MAX_ROWS)], axis=0)
-        ids = [self.tok.encode(t)[: self.max_len] for t in texts]
-        n = len(ids)
-        rows = bucket(n, 8)
-        L = bucket(max(len(i) for i in ids), 16, self.max_len)
-        toks = np.zeros((rows, L), np.int32)
-        mask = np.zeros((rows, L), np.float32)
-        for i, seq in enumerate(ids):
-            toks[i, : len(seq)] = seq
-            mask[i, : len(seq)] = 1.0
-        self.stats.calls += 1
-        self.stats.tokens += int(mask.sum())
-        self.stats.texts += n
-        out = self._pooled(self.params, jnp.asarray(toks), jnp.asarray(mask))
-        return np.asarray(out)[:n]
+        with self.obs.span("encoder.forward") as sp:
+            with self.obs.span("encoder.tokenize"):
+                ids = [self.tok.encode(t)[: self.max_len] for t in texts]
+                n = len(ids)
+                rows = bucket(n, 8)
+                L = bucket(max(len(i) for i in ids), 16, self.max_len)
+                toks = np.zeros((rows, L), np.int32)
+                mask = np.zeros((rows, L), np.float32)
+                for i, seq in enumerate(ids):
+                    toks[i, : len(seq)] = seq
+                    mask[i, : len(seq)] = 1.0
+            tokens = int(mask.sum())
+            self.stats.calls += 1
+            self.stats.tokens += tokens
+            self.stats.texts += n
+            sp.set(texts=n, rows=rows, width=L, tokens=tokens,
+                   padded_tokens=rows * L)
+            with self.obs.span("encoder.device"):
+                out = np.asarray(self._pooled(self.params, jnp.asarray(toks),
+                                              jnp.asarray(mask)))
+        return out[:n]
 
     def encode_one(self, text: str) -> np.ndarray:
         return self.encode([text])[0]

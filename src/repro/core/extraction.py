@@ -105,7 +105,6 @@ class ParallelExtractor:
         Dependency depth stays 1 regardless of batch size.
 
         Returns ([SessionExtraction, ...], WriteStats)."""
-        t0 = time.perf_counter()
         per_chunks: List[List[Tuple[int, str, float]]] = []
         per_cands: List[List[RawCandidate]] = []
         texts: List[str] = []
@@ -138,7 +137,6 @@ class ParallelExtractor:
             pos += len(cands)
 
         stats = WriteStats(
-            wall_s=time.perf_counter() - t0,
             llm_dependency_depth=1 if texts else 0,
             facts_written=sum(len(c) for c in per_cands),
         )

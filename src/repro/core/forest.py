@@ -224,14 +224,15 @@ class Forest:
                 for j, c in enumerate(kids):
                     child_emb[i, j] = tree.emb[c]
                     mask[i, j] = 1.0
-            if self.mesh is not None:
-                out = np.asarray(shard_ops.sharded_tree_refresh(
-                    child_emb, mask, mesh=self.mesh, axis=self.mesh_axis,
-                    impl=self.kernel_impl))
-            else:
-                out = np.asarray(ops.tree_refresh(
-                    jnp.asarray(child_emb), jnp.asarray(mask),
-                    impl=self.kernel_impl))
+            with self.obs.span("forest.tree_refresh.device"):
+                if self.mesh is not None:
+                    out = np.asarray(shard_ops.sharded_tree_refresh(
+                        child_emb, mask, mesh=self.mesh, axis=self.mesh_axis,
+                        impl=self.kernel_impl))
+                else:
+                    out = np.asarray(ops.tree_refresh(
+                        jnp.asarray(child_emb), jnp.asarray(mask),
+                        impl=self.kernel_impl))
             for i, (tree, n) in enumerate(batch):
                 tree.emb[n] = out[i]
                 tree.refresh_text(n)

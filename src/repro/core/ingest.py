@@ -25,6 +25,11 @@ sessions in the same order (same facts, same tree structure, same query
 answers) — tests/test_ingest_batch.py asserts this — while encoder forwards
 and refresh kernel launches stop scaling with the number of sessions.
 
+Phases 1–3 are the spans ``ingest.extract`` (attribute ``texts``
+embedded), ``ingest.canonicalize`` (``candidates``, ``facts``) and
+``ingest.route`` (``cells``, ``facts``, ``scenes`` after the batch), on the
+forest's observability handle; the flush is ``forest.flush``.
+
 Multi-device serve: when the Forest carries a mesh (``Forest.set_mesh``),
 the flush's per-level ``tree_refresh`` batches are additionally padded to a
 shard multiple and sharded over the mesh's data axis inside
@@ -69,20 +74,34 @@ class IngestBatcher:
         t0 = time.perf_counter()
         tok0 = encoder.stats.tokens
         call0 = encoder.stats.calls
+        texts0 = encoder.stats.texts
         refresh0 = self.forest.summary_refreshes
+        obs = self.forest.obs
 
-        extractions, ex_stats = self.extractor.extract_sessions(sessions)
-        per_session_facts = canonical.canonicalize_batch(
-            [(e.candidates, e.fact_embs) for e in extractions],
-            self.forest,
-            sim_threshold=self.config.canonical_sim_threshold,
-        )
-        for ext, facts in zip(extractions, per_session_facts):
-            for cell in ext.cells:
-                self.forest.add_cell(cell)
-                routing.materialize_cell(cell, self.forest)
-            for f in facts:
-                routing.materialize_fact(f, self.forest)
+        with obs.span("ingest.extract") as sp:
+            extractions, ex_stats = self.extractor.extract_sessions(sessions)
+            if obs.enabled:     # the counts cost nothing with tracing off
+                sp.set(texts=encoder.stats.texts - texts0)
+        with obs.span("ingest.canonicalize") as sp:
+            per_session_facts = canonical.canonicalize_batch(
+                [(e.candidates, e.fact_embs) for e in extractions],
+                self.forest,
+                sim_threshold=self.config.canonical_sim_threshold,
+            )
+            if obs.enabled:
+                sp.set(candidates=sum(len(e.candidates) for e in extractions),
+                       facts=sum(len(f) for f in per_session_facts))
+        with obs.span("ingest.route") as sp:
+            for ext, facts in zip(extractions, per_session_facts):
+                for cell in ext.cells:
+                    self.forest.add_cell(cell)
+                    routing.materialize_cell(cell, self.forest)
+                for f in facts:
+                    routing.materialize_fact(f, self.forest)
+            if obs.enabled:
+                sp.set(cells=sum(len(e.cells) for e in extractions),
+                       facts=sum(len(f) for f in per_session_facts),
+                       scenes=len(self.forest.scene_counts))
 
         levels = 0
         if flush:

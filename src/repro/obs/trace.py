@@ -15,7 +15,16 @@ benches stays ≤2% when tracing is off.
 
 Enabled cost per span: two ``perf_counter`` reads, a stack push/pop, one
 histogram record (into the owning component's registry, name
-``span/<name>``), and — only when a sink is attached — one JSONL line.
+``span/<name>``), one profiler TraceMe (a small part of the span's
+cost, even under a running profiler), and — only when a sink is
+attached — one JSONL line.
+
+Profiler clock: while tracing is enabled every span also enters a
+``jax.profiler.TraceAnnotation`` of its name (attributes stay in the
+trace records: keyword arguments would be folded into the event's name).
+So any ``jax.profiler`` capture shows the program's spans on the host's
+python line, on the same clock as the device's ops. ``jax`` is imported
+when tracing is enabled, not with this module.
 
 Trace format (one JSON object per line)::
 
@@ -119,7 +128,7 @@ class Span:
     histogram and (if a sink is attached) a JSONL line is emitted."""
 
     __slots__ = ("tracer", "registry", "name", "attrs", "span_id",
-                 "parent_id", "t_start", "dur_s")
+                 "parent_id", "t_start", "dur_s", "_traceme")
 
     def __init__(self, tracer: "Tracer", name: str, registry, attrs):
         self.tracer = tracer
@@ -151,11 +160,14 @@ class Span:
         stack = tr._stack()
         self.parent_id = stack[-1].span_id if stack else None
         stack.append(self)
+        self._traceme = tr.annotation(self.name)
+        self._traceme.__enter__()
         self.t_start = perf_counter() - tr.t0
         return self
 
     def __exit__(self, *exc) -> bool:
         self.dur_s = perf_counter() - self.tracer.t0 - self.t_start
+        self._traceme.__exit__(None, None, None)
         stack = self.tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -173,11 +185,13 @@ class Span:
 
 
 class Tracer:
-    """Enabled flag + sink + id allocator + per-thread span stack."""
+    """Enabled flag + sink + id allocator + per-thread span stack, and the
+    profiler annotation each span enters (resolved by :meth:`enable`)."""
 
     def __init__(self, sink=None, enabled: bool = False):
         self.enabled = enabled
         self.sink = sink
+        self.annotation = _trace_annotation() if enabled else None
         self.t0 = perf_counter()
         self._id = 0
         self._id_lock = threading.Lock()
@@ -227,6 +241,7 @@ class Tracer:
         self.sink = sink
         self.t0 = perf_counter()
         self._id = 0
+        self.annotation = _trace_annotation()
         self.enabled = True
         return self
 
@@ -235,6 +250,13 @@ class Tracer:
         sink, self.sink = self.sink, None
         if sink is not None:
             sink.flush()
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported only once tracing is
+    enabled, so this module imports without jax."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
 
 
 #: process-wide default tracer — components fall back to this one, so

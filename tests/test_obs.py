@@ -405,3 +405,116 @@ def test_tracer_event_races_disable_without_crashing():
         tr.disable()
         t.join()
     assert not errors
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock: each span enters a TraceMe of its name
+# ---------------------------------------------------------------------------
+def _profiled_spans(tmp_path, tracing: bool):
+    """Nested spans around a jitted call under ``jax.profiler.trace``;
+    returns {name: (start_ns, end_ns)} of the span names found on any line
+    of a ``/host:`` plane (its python line is ``python`` or ``python3``)."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    f = jax.jit(lambda x: x * 2.0 + 1.0)
+    x = jnp.ones(16)
+    f(x).block_until_ready()
+    if tracing:
+        obs.enable_tracing(MemorySink())
+    o = Observability()
+    with jax.profiler.trace(str(tmp_path)):
+        with o.span("obs.test.parent", n=3):
+            with o.span("obs.test.child"):
+                f(x).block_until_ready()
+    obs.disable_tracing()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("obs.test."):
+                    found[e.name] = (e.start_ns, e.start_ns + e.duration_ns)
+    return found
+
+
+def test_spans_reach_the_profiler_trace_nested(tmp_path):
+    found = _profiled_spans(tmp_path, tracing=True)
+    assert set(found) == {"obs.test.parent", "obs.test.child"}, found
+    (pa, pb), (ca, cb) = found["obs.test.parent"], found["obs.test.child"]
+    assert pa <= ca < cb <= pb
+
+
+def test_disabled_spans_leave_no_profiler_event(tmp_path):
+    assert _profiled_spans(tmp_path, tracing=False) == {}
+
+
+class _RecordingAnnotation:
+    """Stands in for ``TraceAnnotation``: records each enter and exit."""
+    log = []
+
+    def __init__(self, *args, **kwargs):
+        self.args, self.kwargs = args, kwargs
+
+    def __enter__(self):
+        self.log.append(("enter", self.args, self.kwargs))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.args, self.kwargs))
+
+
+def _recording_tracer():
+    tr = obs.Tracer().enable(MemorySink())
+    tr.annotation = _RecordingAnnotation
+    _RecordingAnnotation.log = []
+    return tr, Observability(tracer=tr)
+
+
+def test_span_enters_a_traceme_of_its_name_only():
+    tr, o = _recording_tracer()
+    with o.span("outer", n=3):
+        with o.span("inner", k="v"):
+            pass
+    assert _RecordingAnnotation.log == [
+        ("enter", ("outer",), {}), ("enter", ("inner",), {}),
+        ("exit", ("inner",), {}), ("exit", ("outer",), {})]
+
+
+def test_traceme_is_left_when_the_body_raises():
+    tr, o = _recording_tracer()
+    with pytest.raises(ValueError):
+        with o.span("outer"):
+            raise ValueError("boom")
+    assert [e[0] for e in _RecordingAnnotation.log] == ["enter", "exit"]
+    assert tr.sink.spans("outer")            # the span was still recorded
+
+
+def test_enable_resolves_the_profiler_annotation():
+    from jax.profiler import TraceAnnotation
+
+    tr = obs.Tracer()
+    assert tr.annotation is None
+    assert tr.enable().annotation is TraceAnnotation
+    assert obs.Tracer(enabled=True).annotation is TraceAnnotation
+
+
+def test_obs_imports_and_runs_disabled_without_jax():
+    import os
+    import subprocess
+    import sys
+
+    code = ("import sys; sys.modules['jax'] = None\n"
+            "from repro import obs\n"
+            "o = obs.Observability()\n"
+            "with o.span('x') as sp: sp.set(n=1)\n"
+            "assert sp is obs.NULL_SPAN\n")
+    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    r = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True,
+                       text=True, timeout=60)
+    assert r.returncode == 0, r.stderr
