@@ -1,15 +1,30 @@
-"""Tiled causal GQA flash attention (prefill) — Pallas TPU kernel.
+"""Causal GQA flash attention (prefill) — Pallas TPU kernels.
 
-Grid layout: (batch, q_heads, num_q_blocks, num_kv_blocks) with the KV-block
-dimension innermost and sequential ("arbitrary"), so the running softmax
-statistics (m, l) and the fp32 output accumulator live in VMEM scratch and
-carry across KV iterations. Causal blocks above the diagonal are skipped.
+``flash_attention`` picks one of two paths from the input shape.
 
-VMEM working set per step: q tile (block_q, D) + k/v tiles (block_kv, D) each
-in input dtype, plus fp32 scratch (block_q, D) + 2*(block_q, 1). With the
-default block_q = block_kv = 512 and D = 128 that is ~0.7 MB — comfortably
-inside VMEM — and MXU contractions are (512 x 128 x 512), all multiples of
-the 128-lane systolic array.
+Packed path, for a sequence that fits one tile (``S <= block_q`` and
+``S <= block_kv``): one grid step handles G (row, kv-head) slabs. The
+``g = Hq / Hkv`` query heads of one kv head lie along the rows of its q
+slab, so q is (B*Hkv, g*S, D) against k and v (B*Hkv, S, D), and each slab
+is one batched contraction over the leading dimension; query row r holds
+position ``r mod S``, which is what the causal mask compares. One kv block
+covers the sequence, so the softmax is one pass with no running statistics
+and no kv grid axis. G (``slabs_per_step``) is the largest power of two
+that divides B*Hkv, puts at most ``PACKED_ROWS`` query rows in a step, and
+keeps ``packed_vmem_bytes`` (double-buffered tiles plus f32 temporaries)
+under ``VMEM_BUDGET``; where even one slab does not fit, the tiled path
+runs. An encoder forward of 256 rows (widths 16-128, 32 heads of 96,
+bf16) thus takes 64-512 steps a layer in place of 8192, one per (row, head).
+
+Tiled path, otherwise: grid (batch, q_heads, num_q_blocks, num_kv_blocks)
+with the kv-block dimension innermost and sequential ("arbitrary"), so the
+running softmax statistics (m, l) and the fp32 output accumulator live in
+VMEM scratch and carry across kv iterations. Causal blocks above the
+diagonal are skipped. With block_q = block_kv = 512 and D = 128 a step
+holds ~0.7 MB, and the contractions are (512 x 128 x 512).
+
+Both paths upcast q, k and v to fp32 and keep the softmax and the
+accumulation in fp32; the output has q's dtype.
 """
 from __future__ import annotations
 
@@ -24,6 +39,88 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_KV = 512
 NEG_INF = -1e30
+PACKED_ROWS = 2048              # query rows a packed step aims for
+VMEM_BUDGET = 12 * 1024 * 1024  # of 16 MiB scoped VMEM; room for what the estimate misses
+
+
+def _tile_bytes(lead: int, rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (lead, rows, cols) array, padded to the (sublane,
+    128-lane) tile of its dtype."""
+    sub = 8 * max(1, 4 // itemsize)
+    return lead * -(-rows // sub) * sub * -(-cols // 128) * 128 * itemsize
+
+
+def packed_vmem_bytes(slabs: int, group: int, seq: int, head_dim: int,
+                      itemsize: int) -> int:
+    """VMEM a packed step of ``slabs`` slabs holds: the q, k, v and output
+    tiles double-buffered in the input dtype, and the fp32 temporaries
+    (upcast q, k, v, scores, probabilities, output)."""
+    rows = group * seq
+    tiles = (2 * _tile_bytes(slabs, rows, head_dim, itemsize)
+             + 2 * _tile_bytes(slabs, seq, head_dim, itemsize))
+    temps = (2 * _tile_bytes(slabs, rows, head_dim, 4)
+             + 2 * _tile_bytes(slabs, seq, head_dim, 4)
+             + 2 * _tile_bytes(slabs, rows, seq, 4))
+    return 2 * tiles + temps
+
+
+def slabs_per_step(n_slabs: int, group: int, seq: int, head_dim: int,
+                   itemsize: int) -> int:
+    """G for the packed path: the largest power of two that divides
+    ``n_slabs`` (= B*Hkv), holds at most ``PACKED_ROWS`` query rows (at
+    least one slab) and fits ``VMEM_BUDGET``; 0 where one slab does not fit."""
+    fits = lambda n: packed_vmem_bytes(n, group, seq, head_dim, itemsize) <= VMEM_BUDGET
+    slabs = 1
+    while (n_slabs % (2 * slabs) == 0 and 2 * slabs * group * seq <= PACKED_ROWS
+           and fits(2 * slabs)):
+        slabs *= 2
+    return slabs if fits(slabs) else 0
+
+
+def _packed_kernel(q_ref, k_ref, v_ref, o_ref, *, causal: bool, seq: int,
+                   sm_scale: float):
+    # q_ref, o_ref: (G, g*S, D); k_ref, v_ref: (G, S, D)
+    q = q_ref[...].astype(jnp.float32)
+    k = k_ref[...].astype(jnp.float32)
+    v = v_ref[...].astype(jnp.float32)
+    s = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+    ) * sm_scale                                       # (G, g*S, S)
+    if causal:
+        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape[1:], 0) % seq
+        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape[1:], 1)
+        s = jnp.where((rows >= cols)[None], s, NEG_INF)
+    m = jnp.max(s, axis=2, keepdims=True)
+    p = jnp.exp(s - m)
+    l = jnp.maximum(jnp.sum(p, axis=2, keepdims=True), 1e-30)
+    o = jax.lax.dot_general(
+        p, v, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
+    )                                                  # (G, g*S, D)
+    o_ref[...] = (o / l).astype(o_ref.dtype)
+
+
+def _packed_attention(q, k, v, *, causal: bool, slabs: int, interpret: bool):
+    B, S, Hq, D = q.shape
+    Hkv = k.shape[2]
+    g = Hq // Hkv
+    n = B * Hkv
+    qp = q.reshape(B, S, Hkv, g, D).transpose(0, 2, 3, 1, 4).reshape(n, g * S, D)
+    kp = k.transpose(0, 2, 1, 3).reshape(n, S, D)
+    vp = v.transpose(0, 2, 1, 3).reshape(n, S, D)
+    kernel = functools.partial(_packed_kernel, causal=causal, seq=S,
+                               sm_scale=1.0 / (D ** 0.5))
+    q_spec = pl.BlockSpec((slabs, g * S, D), lambda i: (i, 0, 0))
+    kv_spec = pl.BlockSpec((slabs, S, D), lambda i: (i, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid=(n // slabs,),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((n, g * S, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(qp, kp, vp)
+    return out.reshape(B, Hkv, g, S, D).transpose(0, 3, 1, 2, 4).reshape(B, S, Hq, D)
 
 
 def _flash_kernel(
@@ -93,6 +190,11 @@ def flash_attention(
     B, S, Hq, D = q.shape
     Hkv = k.shape[2]
     group = Hq // Hkv
+    if S <= block_q and S <= block_kv:
+        slabs = slabs_per_step(B * Hkv, group, S, D, q.dtype.itemsize)
+        if slabs:
+            return _packed_attention(q, k, v, causal=causal, slabs=slabs,
+                                     interpret=interpret)
     block_q = min(block_q, S)
     block_kv = min(block_kv, S)
     assert S % block_q == 0 and S % block_kv == 0, (S, block_q, block_kv)

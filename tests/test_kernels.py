@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import flash_attention as fa, ops, ref
 
 ATOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
@@ -15,17 +15,35 @@ def _mk(rng, shape, dtype=jnp.float32, scale=1.0):
 
 
 # ---------------------------------------------------------------------------
+def _grid(q, k, v, **kw):
+    """The grid of the one Pallas call ``flash_attention`` launches: rank 1
+    on the packed path, rank 4 on the tiled one."""
+    jaxpr = jax.make_jaxpr(lambda q, k, v: fa.flash_attention(q, k, v, interpret=True, **kw))
+    (eqn,) = [e for e in jaxpr(q, k, v).eqns if e.primitive.name == "pallas_call"]
+    return eqn.params["grid_mapping"].grid
+
+
 @pytest.mark.parametrize("B,S,Hq,Hkv,D,bq,bk", [
     (1, 128, 4, 4, 32, 64, 64),      # MHA
     (2, 256, 8, 2, 64, 64, 128),     # GQA 4:1
     (1, 512, 4, 1, 16, 128, 256),    # MQA
     (2, 128, 6, 2, 24, 32, 64),      # non-pow2 head_dim
+    # packed path: the whole sequence fits one tile
+    (2, 16, 4, 4, 96, 512, 512),     # MHA, encoder head_dim
+    (2, 128, 4, 4, 96, 512, 512),    # MHA, encoder head_dim, widest encoder text
+    (2, 32, 8, 2, 64, 512, 512),     # GQA 4:1
+    (2, 32, 4, 1, 32, 512, 512),     # MQA
+    (3, 16, 2, 2, 32, 512, 512),     # B*Hkv = 6: G = 2, not the 128 rows allow
+    (3, 32, 4, 1, 32, 32, 32),       # B*Hkv = 3: one slab a step; S == block
+    (2, 256, 4, 4, 96, 128, 128),    # S > block: tiled path
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention(rng, B, S, Hq, Hkv, D, bq, bk, dtype):
     q = _mk(rng, (B, S, Hq, D), dtype)
     k = _mk(rng, (B, S, Hkv, D), dtype)
     v = _mk(rng, (B, S, Hkv, D), dtype)
+    packed = S <= bq and S <= bk
+    assert len(_grid(q, k, v, block_q=bq, block_kv=bk)) == (1 if packed else 4)
     out_ref = ops.attention(q, k, v, impl="reference")
     out_pal = ops.attention(q, k, v, impl="pallas_interpret", block_q=bq, block_kv=bk)
     np.testing.assert_allclose(
@@ -42,6 +60,54 @@ def test_flash_attention_noncausal(rng):
     o2 = ops.attention(q, k, v, causal=False, impl="pallas_interpret",
                        block_q=64, block_kv=64)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-5, rtol=1e-3)
+
+
+@pytest.mark.parametrize("B,S,Hq,Hkv,D", [
+    (2, 16, 4, 4, 96), (2, 128, 4, 4, 96), (2, 32, 8, 2, 64), (2, 32, 4, 1, 32),
+    (3, 16, 2, 2, 32),
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_packed_noncausal(rng, B, S, Hq, Hkv, D, dtype):
+    q = _mk(rng, (B, S, Hq, D), dtype)
+    k = _mk(rng, (B, S, Hkv, D), dtype)
+    v = _mk(rng, (B, S, Hkv, D), dtype)
+    assert len(_grid(q, k, v, causal=False)) == 1
+    o1 = ops.attention(q, k, v, causal=False, impl="reference")
+    o2 = ops.attention(q, k, v, causal=False, impl="pallas_interpret")
+    np.testing.assert_allclose(np.asarray(o1, np.float32), np.asarray(o2, np.float32),
+                               atol=ATOL[dtype], rtol=1e-2)
+
+
+ENCODER = [(rows, width, 32, 32, 96) for rows in (8, 16, 32, 64, 128, 256)
+           for width in (16, 32, 64, 128)]
+GRANITE = [(rows, width, 32, 8, 128) for rows in (1, 8, 64, 256)
+           for width in (16, 32, 64, 128)]
+
+
+@pytest.mark.parametrize("itemsize", [2, 4])
+def test_packed_slabs_rule(itemsize):
+    """G divides B*Hkv, is a power of two, keeps the VMEM estimate under
+    the budget (itself under the 16 MiB scoped limit) and the rows of a
+    step at the target, and is the largest such G."""
+    assert fa.VMEM_BUDGET <= 16 * 2**20
+    for B, S, Hq, Hkv, D in ENCODER + GRANITE:
+        n, g = B * Hkv, Hq // Hkv
+        G = fa.slabs_per_step(n, g, S, D, itemsize)
+        assert G >= 1 and n % G == 0 and G & (G - 1) == 0, (B, S, G)
+        assert fa.packed_vmem_bytes(G, g, S, D, itemsize) <= fa.VMEM_BUDGET
+        assert G == 1 or G * g * S <= fa.PACKED_ROWS
+        grows = (n % (2 * G) == 0 and 2 * G * g * S <= fa.PACKED_ROWS
+                 and fa.packed_vmem_bytes(2 * G, g, S, D, itemsize) <= fa.VMEM_BUDGET)
+        assert not grows, (B, S, G)
+
+
+def test_packed_slabs_steps():
+    """The encoder's forwards take at most 512 steps a layer at bf16; a
+    shape whose single slab would not fit VMEM gets 0 (the tiled path)."""
+    for B, S, Hq, Hkv, D in ENCODER:
+        assert B * Hkv // fa.slabs_per_step(B * Hkv, 1, S, D, 2) <= 512
+    assert fa.slabs_per_step(8 * 8, 4, 512, 128, 2) == 0
+    assert fa.packed_vmem_bytes(1, 4, 512, 128, 2) > fa.VMEM_BUDGET
 
 
 def test_blockwise_causal_matches_exact(rng):

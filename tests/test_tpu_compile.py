@@ -80,9 +80,15 @@ def test_tree_refresh_compiles(one_chip, p):
              one_chip, ((p, 8, D), F32), ((p, 8), F32))
 
 
-@pytest.mark.parametrize("s", [16, 64, 128, 1024])
-def test_flash_attention_compiles(one_chip, s):
-    qkv = ((8, s, HEADS, HEAD_DIM), BF16)
+@pytest.mark.parametrize("b,s", [
+    pytest.param(8, 16, id="16"), pytest.param(8, 64, id="64"),
+    pytest.param(8, 128, id="128"),
+    pytest.param(8, 1024, id="1024"),       # past one tile: the tiled path
+    pytest.param(256, 128, id="256x128"),   # encoder forwards: the packed path
+    pytest.param(256, 32, id="256x32"),
+])
+def test_flash_attention_compiles(one_chip, b, s):
+    qkv = ((b, s, HEADS, HEAD_DIM), BF16)
     _compile(lambda q, k, v: ops.attention(q, k, v, impl="pallas"),
              one_chip, qkv, qkv, qkv)
 
