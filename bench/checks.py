@@ -1,5 +1,7 @@
 """What decides ``correct``: the outputs of the timed path against the plain
-references, each number against its limit.
+references, each number against its limit. The model's reference is that
+of the configuration's architecture (``arch``: its ``bench/arch/<arch>.py``);
+the memory kernels' is ``reference.py``.
 
 * ``lm_gap``: a seeded sample of the LM requests the window finished, the
   longest among them. The reference runs once over each prompt with its
@@ -43,7 +45,7 @@ import reference
 from tokenizer import HashTokenizer
 
 
-def lm_gap(params, cfg: dict, lms: List, rng: random.Random, n: int,
+def lm_gap(arch, params, cfg: dict, lms: List, rng: random.Random, n: int,
            control: bool = False) -> Optional[dict]:
     done = [r for r in lms if r.done is not None and r.obj is not None
             and r.obj.out_tokens]
@@ -55,10 +57,9 @@ def lm_gap(params, cfg: dict, lms: List, rng: random.Random, n: int,
     for r in pick:
         prompt, out = list(r.payload[0]), list(r.obj.out_tokens)
         toks = prompt + out
-        ref = reference.forward(params, cfg, toks, len(prompt) - 1, len(out))
+        ref = arch.forward(params, cfg, toks, len(prompt) - 1, len(out))
         if control:
-            low = reference.forward(params, cfg, toks, len(prompt) - 1,
-                                    len(out), fp8=True)
+            low = arch.forward(params, cfg, toks, len(prompt) - 1, len(out), fp8=True)
             out = list(np.argmax(low, axis=-1))
         best = ref.max(axis=-1)
         gaps = best - ref[np.arange(len(out)), out]
@@ -67,7 +68,7 @@ def lm_gap(params, cfg: dict, lms: List, rng: random.Random, n: int,
     return {"value": worst, "compared": count}
 
 
-def encoder_gap(params, cfg: dict, samples: List, rng: random.Random, n: int,
+def encoder_gap(arch, params, cfg: dict, samples: List, rng: random.Random, n: int,
                 width: int, control: bool = False,
                 index_rows: List = ()) -> Optional[dict]:
     rows = [(t, e) for texts, embs in samples for t, e in zip(texts, embs)]
@@ -80,8 +81,8 @@ def encoder_gap(params, cfg: dict, samples: List, rng: random.Random, n: int,
     worst = 0.0
     for text, emb in pick:
         ids = tok.encode(text)[:width] or [0]
-        ref = reference.embed(params, cfg, ids, width)
-        got = (reference.embed(params, cfg, ids, width, fp8=True) if control
+        ref = arch.embed(params, cfg, ids, width)
+        got = (arch.embed(params, cfg, ids, width, fp8=True) if control
                else np.asarray(emb, np.float64))
         worst = max(worst, float(np.linalg.norm(got - ref)))
     return {"value": worst, "compared": len(pick)}

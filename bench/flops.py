@@ -7,9 +7,9 @@ that is *needed*: real tokens, not padding, and each operand read once. A
 kernel that does more (padding, re-reads) reads a lower share; none can
 read above 100%.
 
-The whole-model counts follow the analytic model of the program's
-``launch/roofline.py`` (``fwd_flops_per_token``), copied so that the
-yardstick stays fixed.
+Counts that depend on the model's architecture (the whole forward, and
+which attention calls it makes) live in ``bench/arch/<arch>.py``, built
+from the per-call counts here.
 """
 from __future__ import annotations
 
@@ -45,37 +45,3 @@ def roofline_seconds(flops: float, bytes_: float, peaks: dict) -> Tuple[float, s
     tc = flops / peaks["flops_bf16"]
     tb = bytes_ / peaks["hbm_bytes_per_s"]
     return (tc, "compute") if tc >= tb else (tb, "bandwidth")
-
-
-def layer_matmul_flops(cfg: dict) -> float:
-    """Projection and MLP flops of one decoder layer for one token."""
-    d, h, kvh = cfg["hidden_size"], cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = d // h
-    proj = 2 * d * (h * hd + 2 * kvh * hd) + 2 * h * hd * d
-    mlp = 3 * 2 * d * cfg["intermediate_size"]            # SwiGLU: gate, up, down
-    return float(proj + mlp)
-
-
-def model_flops(cfg: dict, lengths: Iterable[int], *, logits_per_seq: int) -> float:
-    """Forward flops of the trunk over sequences of ``lengths`` real tokens
-    (causal attention over each), plus ``logits_per_seq`` output rows of
-    the LM head per sequence (0 for the encoder's pooled forward)."""
-    h = cfg["num_attention_heads"]
-    att, _ = causal_attention(lengths, heads=h, kv_heads=cfg["num_key_value_heads"],
-                              head_dim=cfg["hidden_size"] // h)
-    lengths = list(lengths)
-    dense = layer_matmul_flops(cfg) * sum(lengths)
-    head = 2.0 * cfg["hidden_size"] * cfg["vocab_size"] * logits_per_seq * len(lengths)
-    return cfg["num_hidden_layers"] * (dense + att) + head
-
-
-def decode_flops(cfg: dict, lengths: Iterable[int]) -> float:
-    """Forward flops of one decode step for lanes whose caches hold
-    ``lengths`` tokens (including the one being decoded), LM head included."""
-    h = cfg["num_attention_heads"]
-    lengths = list(lengths)
-    att, _ = decode_attention(lengths, heads=h, kv_heads=cfg["num_key_value_heads"],
-                              head_dim=cfg["hidden_size"] // h)
-    dense = layer_matmul_flops(cfg) * len(lengths)
-    head = 2.0 * cfg["hidden_size"] * cfg["vocab_size"] * len(lengths)
-    return cfg["num_hidden_layers"] * (dense + att) + head

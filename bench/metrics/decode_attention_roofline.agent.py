@@ -2,8 +2,6 @@
 attention over the caches of every lane that decoded in the window needs
 (each layer, each step), over the kernel's time in the device trace.
 Reading the cache bounds it."""
-import flops
-
 UNIT = "%"
 BETTER = "higher"
 SOURCE = "device_trace"
@@ -12,9 +10,8 @@ MOVES = "token_gap_p95_ms"
 
 
 def read(run):
-    layers = run.cfg["num_hidden_layers"]
     calls = []
     for lengths in run.window.decode_lengths:
         if lengths:
-            calls += [flops.decode_attention(lengths, **run.heads())] * layers
+            calls += run.arch.decode_attention_calls(run.cfg, lengths)
     return run.roofline("decode_attention", calls) if calls else None

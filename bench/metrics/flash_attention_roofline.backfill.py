@@ -2,8 +2,6 @@
 attention over the real tokens of every encoder call in the window needs
 (each layer, max of flops at peak and bytes at peak bandwidth), over the
 kernel's time in the device trace. Bandwidth bounds it at these lengths."""
-import flops
-
 UNIT = "%"
 BETTER = "higher"
 SOURCE = "device_trace"
@@ -12,9 +10,7 @@ MOVES = "ingest_tokens_per_s"
 
 
 def read(run):
-    layers = run.cfg["num_hidden_layers"]
     calls = []
     for lengths in run.encoder_lengths():
-        f, b = flops.causal_attention(lengths, **run.heads())
-        calls += [(f, b)] * layers
+        calls += run.arch.prefill_attention_calls(run.cfg, lengths)
     return run.roofline("flash_attention", calls) if calls else None

@@ -3,13 +3,15 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Set-up builds the configuration's model with weights made from the seed,
-its memory (journal fsynced on every write) and one ServeEngine; writes the
-history the mix preloads; warms up every shape the window can use. The
-window then drives the engine with the mix's traffic for ``--seconds``.
-With ``--trace 0`` the result carries the cell's end-to-end metrics; with
-``--trace 1`` the program's spans and the profiler are on, and it carries
-the per-layer metrics, the device's busy time and a breakdown.
+Set-up builds the configuration's model, as its architecture's module
+(``bench/arch/<arch>.py``, named by the file's ``"arch"``) describes it,
+with weights made from the seed, its memory (journal fsynced on every
+write) and one ServeEngine; writes the history the mix preloads; warms up
+every shape the window can use. The window then drives the engine with
+the mix's traffic for ``--seconds``. With ``--trace 0`` the result carries
+the cell's end-to-end metrics; with ``--trace 1`` the program's spans and
+the profiler are on, and it carries the per-layer metrics, the device's
+busy time and a breakdown.
 
 After the window, with the device's peak memory read and the engine freed,
 the outputs of the timed path are compared with plain references
@@ -91,9 +93,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     compiles = CompileLog()
     dev = jax.devices()[0]
     on_tpu = dev.platform == "tpu"
-    cfg, mix = cell.config, cell.traffic
+    cfg, mix, arch = cell.config, cell.traffic, cell.arch
     rng = random.Random(seed)
-    model = get_model(system.model_config(cfg))
+    model = get_model(arch.model_config(cfg))
     params = system.make_weights(model, seed)
     journal_dir = tempfile.mkdtemp(prefix="bench-journal-")
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
@@ -170,14 +172,14 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
 
     pk = peak_table(dev.device_kind) if on_tpu else None
     view = RunView(cfg, win, setup_s=setup_s, encoder_calls=encoder.calls,
-                   spans=spans, trace=reduced, peaks=pk)
+                   spans=spans, trace=reduced, peaks=pk, arch=arch)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         value = spec.reader(m["name"]).read(view)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
-    numbers = _compare(cfg, mix, params, win, encoder, kprobe, rows,
+    numbers = _compare(arch, cfg, mix, params, win, encoder, kprobe, rows,
                        random.Random(rng.random()), control=False)
     numbers.update(written)
     check_s = time.perf_counter() - t_check
@@ -203,7 +205,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         }
     if control:
         result["control_checks"] = _compare(
-            cfg, mix, params, win, encoder, kprobe, rows,
+            arch, cfg, mix, params, win, encoder, kprobe, rows,
             random.Random(rng.random()), control=True)
     shutil.rmtree(journal_dir, ignore_errors=True)
     late = sorted(r.submitted - r.due for r in win.requests if r.submitted is not None)
@@ -236,15 +238,16 @@ def _required(mix: dict) -> list:
     return need
 
 
-def _compare(cfg, mix, params, win, encoder, kprobe, index_rows, rng,
+def _compare(arch, cfg, mix, params, win, encoder, kprobe, index_rows, rng,
              *, control: bool) -> dict:
     chk = mix["check"]
     s = kprobe.samples
     return {
-        "lm_gap": checks.lm_gap(params, cfg, [r for r in win.requests if r.kind == "lm"],
+        "lm_gap": checks.lm_gap(arch, params, cfg,
+                                [r for r in win.requests if r.kind == "lm"],
                                 rng, chk["lm_requests"], control=control),
         "encoder_gap": checks.encoder_gap(
-            params, cfg, encoder.sample.items, rng, chk["encoder_texts"],
+            arch, params, cfg, encoder.sample.items, rng, chk["encoder_texts"],
             cfg["serve"]["encoder_max_tokens"], control=control,
             index_rows=index_rows),
         "topk_gap": checks.topk_gap(s["topk_sim"].items, control=control),

@@ -50,7 +50,7 @@ def sweep(cell, seed: int, seconds: float, scales, *, cache: bool = True):
     if cache:
         run.enable_compile_cache()
     cfg, mix = cell.config, cell.traffic
-    model = get_model(system.model_config(cfg))
+    model = get_model(cell.arch.model_config(cfg))
     params = system.make_weights(model, seed)
     kprobe = system.KernelProbe(random.Random(seed))
     journal_dir = tempfile.mkdtemp(prefix="bench-sweep-")
@@ -69,7 +69,7 @@ def sweep(cell, seed: int, seconds: float, scales, *, cache: bool = True):
             plan.questions, plan.preload = base.questions, []
             t0 = time.perf_counter()
             win = loop.run_window(engine, memory, plan, m, seconds)
-            view = RunView(cfg, win, setup_s=0.0, encoder_calls=[])
+            view = RunView(cfg, win, setup_s=0.0, encoder_calls=[], arch=cell.arch)
             out = {"scale": scale,
                    "rates": {n: s["rate_per_s"] for n, s in m["streams"].items()},
                    "wall_s": time.perf_counter() - t0, "close_s": win.close,

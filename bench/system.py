@@ -1,8 +1,9 @@
 """The system under test, built from a configuration file.
 
-The model is the program's decoder (``repro.models``) with the sizes the
-file states and weights that the benchmark makes itself, on the device, in
-one jitted call from the seed. The same model is the memory's encoder
+The model is the program's (``repro.models``), as the configuration's
+architecture module builds it (``bench/arch/<arch>.py``: ``model_config``),
+with weights that the benchmark makes itself, on the device, in one jitted
+call from the seed. The same model is the memory's encoder
 (``ModelEncoder``). Memory is a ``MemForestSystem`` inside a
 ``DurableMemForest`` whose journal fsyncs every write, and the whole is
 served by one ``ServeEngine``.
@@ -26,25 +27,6 @@ import os
 import random
 import time
 from typing import Dict, List, Optional
-
-
-def model_config(cfg: dict):
-    from repro.config import ModelConfig
-
-    h = cfg["num_attention_heads"]
-    if cfg["hidden_act"] != "silu":
-        raise ValueError(f"{cfg['name']}: the served MLP is SwiGLU, "
-                         f"not {cfg['hidden_act']}")
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=h, num_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg["hidden_size"] // h, d_ff=cfg["intermediate_size"],
-        vocab_size=cfg["vocab_size"], rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]),
-        max_seq_len=cfg["max_position_embeddings"], mlp_activation="swiglu",
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        dtype=cfg["torch_dtype"], param_dtype=cfg["torch_dtype"])
 
 
 def seed_key(seed: int):

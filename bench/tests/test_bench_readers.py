@@ -43,7 +43,7 @@ def full():
     return RunView(_cfg(), win, setup_s=12.5,
                    encoder_calls=[["[user] Bob moved from Boston to Miami in May 2021."]],
                    spans=spans, trace=trace_reduce.load(os.path.join(FIXTURES, "probe.xplane.pb")),
-                   peaks=peaks.peaks("TPU v5 lite"))
+                   peaks=peaks.peaks("TPU v5 lite"), arch=spec.arch(_cfg()["arch"]))
 
 
 @pytest.mark.parametrize("name", READERS)
@@ -57,6 +57,7 @@ def test_reads_a_number_where_there_is_one(full, name):
 
 @pytest.mark.parametrize("name", READERS)
 def test_reads_nothing_where_there_is_nothing(name):
-    empty = RunView(_cfg(), Window(close=1.0), setup_s=12.5, encoder_calls=[])
+    empty = RunView(_cfg(), Window(close=1.0), setup_s=12.5, encoder_calls=[],
+                    arch=spec.arch(_cfg()["arch"]))
     value = spec.reader(name).read(empty)
     assert value == (12.5 if name == "setup_s" else None)

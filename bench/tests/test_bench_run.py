@@ -24,7 +24,8 @@ def _cell(kind):
              if kind == "agent" else ["setup_s", "ingest_tokens_per_s"])
     e2e = [{"name": n, "unit": spec.reader(n).UNIT} for n in names]
     return spec.Cell(name=f"tiny.{kind}", chips=1, config_name="tiny", config=cfg,
-                     traffic_name=mix["name"], traffic=mix, end_to_end=e2e)
+                     traffic_name=mix["name"], traffic=mix, end_to_end=e2e,
+                     arch=spec.arch(cfg["arch"]))
 
 
 def _run(kind, seed, **kw):

@@ -1,4 +1,5 @@
-"""Trace reduction, operation counts and the peak table."""
+"""Trace reduction, operation counts (``flops.py`` and the dense
+architecture's) and the peak table."""
 import os
 
 import pytest
@@ -7,7 +8,10 @@ from _paths import FIXTURES
 
 import flops
 import peaks
+import spec
 import trace_reduce
+
+dense = spec.arch("dense")
 
 PROBE = os.path.join(FIXTURES, "probe.xplane.pb")
 
@@ -74,11 +78,17 @@ def test_kernel_signature_when_named_otherwise():
 @pytest.mark.parametrize("name,heads,kv,hd", [("phi3-mini", 32, 32, 96),
                                              ("granite-3-8b-20L", 32, 8, 128)])
 def test_attention_counts_at_both_configurations(name, heads, kv, hd):
-    n = 1024
-    f, b = flops.causal_attention([n], heads=heads, kv_heads=kv, head_dim=hd)
+    n, layers = 1024, 20
+    cfg = {"hidden_size": heads * hd, "num_attention_heads": heads,
+           "num_key_value_heads": kv, "num_hidden_layers": layers}
+    calls = dense.prefill_attention_calls(cfg, [n])
+    assert len(calls) == layers and len(set(calls)) == 1
+    f, b = calls[0]
     assert f == 4 * heads * hd * n * (n + 1) / 2
     assert b == 2 * n * hd * (2 * heads + 2 * kv)
-    f, b = flops.decode_attention([n, 10], heads=heads, kv_heads=kv, head_dim=hd)
+    calls = dense.decode_attention_calls(cfg, [n, 10])
+    assert len(calls) == layers and len(set(calls)) == 1
+    f, b = calls[0]
     assert f == 4 * heads * hd * (n + 10)
     assert b == 2 * (2 * (n + 10) * kv * hd + 2 * 2 * heads * hd)
     # both are bandwidth-bound on a v5e: far under its ridge of ~240 flop/B
@@ -90,8 +100,9 @@ def test_phi3_forward_flops_per_token():
     cfg = {"hidden_size": 3072, "num_attention_heads": 32, "num_key_value_heads": 32,
            "intermediate_size": 8192, "num_hidden_layers": 32, "vocab_size": 32064}
     per_layer = 2 * 3072 * 3 * 3072 + 2 * 3072 * 3072 + 6 * 3072 * 8192
-    assert flops.layer_matmul_flops(cfg) == per_layer
-    one = flops.model_flops(cfg, [1], logits_per_seq=1)
+    trunk = dense.prefill_flops(cfg, [1], logits_per_seq=0)
+    assert trunk == 32 * (per_layer + 4 * 3072)
+    one = dense.prefill_flops(cfg, [1], logits_per_seq=1)
     assert one == 32 * (per_layer + 4 * 3072) + 2 * 3072 * 32064
 
 

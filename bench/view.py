@@ -1,11 +1,13 @@
 """What a metric reader sees of one run: the window's requests and steps,
 the encoder's calls, the program's spans, the reduced device trace, the
-configuration and the device's peaks. Each helper returns what there is;
-a reader returns None where there is nothing to read."""
+configuration, its architecture's module (``bench/arch/<arch>.py``, which
+counts the model's work) and the device's peaks. Each helper returns what
+there is; a reader returns None where there is nothing to read."""
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from types import ModuleType
+from typing import List, Optional
 
 import flops
 from loop import Window
@@ -23,8 +25,10 @@ class Span:
 class RunView:
     def __init__(self, cfg: dict, window: Window, *, setup_s: float,
                  encoder_calls: List[List[str]], spans: Optional[List[Span]] = None,
-                 trace=None, peaks: Optional[dict] = None):
+                 trace=None, peaks: Optional[dict] = None,
+                 arch: Optional[ModuleType] = None):
         self.cfg = cfg
+        self.arch = arch          # bench/arch/<arch>.py: the model's counts
         self.window = window
         self.setup_s = setup_s
         self.encoder_calls = encoder_calls
@@ -82,12 +86,6 @@ class RunView:
         return sum(int(s.attrs.get(attr, 0)) for s in self.spans if s.name == name)
 
     # -- flops ----------------------------------------------------------------
-    def heads(self) -> Dict[str, int]:
-        c = self.cfg
-        return {"heads": c["num_attention_heads"],
-                "kv_heads": c["num_key_value_heads"],
-                "head_dim": c["hidden_size"] // c["num_attention_heads"]}
-
     def peak_share(self, flops_: float) -> Optional[float]:
         """``flops_`` over the window at the chip's peak, in percent."""
         if self.peaks is None or self.window_s <= 0:
