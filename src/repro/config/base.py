@@ -20,7 +20,8 @@ class ModelConfig:
 
     ``family`` selects the block type:
       * ``dense``  — pre-norm GQA transformer (RoPE, SwiGLU or GeLU MLP)
-      * ``moe``    — dense attention + top-k routed expert MLP
+      * ``moe``    — attention + top-k routed expert MLP (optionally shared
+                     experts, leading dense layers, latent attention)
       * ``ssm``    — RWKV6 (attention-free, data-dependent decay)
       * ``hybrid`` — Zamba2: Mamba2 backbone + shared attention block
       * ``encdec`` — Whisper-style encoder-decoder (frame-embedding frontend stub)
@@ -38,9 +39,31 @@ class ModelConfig:
     head_dim: int = 0  # 0 -> d_model // num_heads
 
     # --- MoE ---
-    num_experts: int = 0
+    num_experts: int = 0            # the router's width: every expert of a layer
     experts_per_token: int = 0
-    moe_capacity_factor: float = 1.25
+    moe_capacity_factor: float = 1.25   # mesh (shard_map) dispatch only
+    # the contiguous slice of experts whose weights this device holds
+    # (expert parallelism's share of one chip); 0 = all of them
+    experts_held: int = 0
+    first_expert: int = 0
+    norm_topk_prob: bool = True     # renormalise the top-k gate weights
+    shared_d_ff: int = 0            # shared experts as one SwiGLU; 0 = none
+    first_dense_layers: int = 0     # leading layers with a dense MLP ...
+    dense_d_ff: int = 0             # ... of this width (0 = d_ff)
+
+    # --- multi-head latent attention (MLA); kv_lora_rank 0 = standard ---
+    kv_lora_rank: int = 0           # width of the compressed KV latent
+    qk_nope_head_dim: int = 0       # per-head q/k width without rotation
+    qk_rope_head_dim: int = 0       # per-head q width (and the one shared k) rotated
+    v_head_dim: int = 0
+
+    # --- YaRN rope scaling; rope_factor 1 = plain rope ---
+    rope_factor: float = 1.0
+    rope_original_max_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- SSM / hybrid ---
     ssm_state_dim: int = 0          # Mamba2 N (state size per head)
@@ -83,11 +106,37 @@ class ModelConfig:
             f"{self.name}: num_heads {self.num_heads} not divisible by "
             f"num_kv_heads {self.num_kv_heads}"
         )
+        assert self.first_expert + self.held_experts <= self.num_experts, (
+            f"{self.name}: experts {self.first_expert}.. + {self.held_experts} "
+            f"held exceed the router's {self.num_experts}")
 
     # ---- derived quantities ---------------------------------------------
     @property
     def q_dim(self) -> int:
         return self.num_heads * self.head_dim
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def moe_layers(self) -> int:
+        """Layers with the expert MLP (after the leading dense ones)."""
+        return self.num_layers - self.first_dense_layers if self.family == "moe" else 0
+
+    def attn_params(self) -> int:
+        D = self.d_model
+        if self.is_mla:
+            H, R = self.num_heads, self.kv_lora_rank
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            return (D * H * qk + D * (R + self.qk_rope_head_dim) + R
+                    + R * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * D)
+        return D * self.q_dim + 2 * D * self.kv_dim + self.q_dim * D
 
     @property
     def kv_dim(self) -> int:
@@ -116,11 +165,13 @@ class ModelConfig:
             per_layer = attn + mlp + 2 * D
             return emb + L * per_layer + D
         if self.family == "moe":
-            attn = D * self.q_dim + 2 * D * self.kv_dim + self.q_dim * D
-            n_e = self.experts_per_token if active_only else self.num_experts
-            mlp = 3 * D * F * n_e + D * self.num_experts  # experts + router
-            per_layer = attn + mlp + 2 * D
-            return emb + L * per_layer + D
+            attn = self.attn_params() + 2 * D
+            n_e = self.experts_per_token if active_only else self.held_experts
+            # experts + shared experts + router
+            mlp = 3 * D * F * n_e + 3 * D * self.shared_d_ff + D * self.num_experts
+            dense = 3 * D * (self.dense_d_ff or F)
+            return (emb + L * attn + self.moe_layers * mlp
+                    + self.first_dense_layers * dense + D)
         if self.family == "ssm":  # rwkv6
             H = D // self.rwkv_head_size
             tmix = 4 * D * D + D * D  # r,k,v,o + gate

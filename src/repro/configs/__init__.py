@@ -23,6 +23,7 @@ ARCHS: List[str] = [
     "llama3_8b",
     "granite_3_8b",
     "pixtral_12b",
+    "deepseek_v2_lite",
 ]
 
 # hyphen/dot aliases accepted from CLI
@@ -37,6 +38,7 @@ _ALIASES = {
     "llama3-8b": "llama3_8b",
     "granite-3-8b": "granite_3_8b",
     "pixtral-12b": "pixtral_12b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

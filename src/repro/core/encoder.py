@@ -12,7 +12,8 @@ builder).
 
 Both count calls and tokens so benchmarks can report Table-2-style cost.
 ModelEncoder also opens an ``encoder.forward`` span per forward (children
-``encoder.tokenize`` and ``encoder.device``) carrying its padded shape.
+``encoder.tokenize`` and ``encoder.device``) carrying its padded shape and,
+for an expert model, the rows its expert layers computed.
 """
 from __future__ import annotations
 
@@ -181,8 +182,12 @@ class ModelEncoder:
 
     Each forward is an ``encoder.forward`` span (attributes ``texts``,
     ``rows``, ``width``, ``tokens``: real, ``padded_tokens``: rows x
-    width) with children ``encoder.tokenize`` (host tokenizing and padding)
-    and ``encoder.device`` (dispatch to the host copy of the result)."""
+    width; for an expert model also ``expert_rows``, the (token, expert)
+    rows its expert layers computed here, summed over layers, and
+    ``expert_rows_bound``, the rows of their largest dispatch buffers) with
+    children ``encoder.tokenize`` (host tokenizing and padding) and
+    ``encoder.device`` (dispatch to the host copy of the result). The
+    expert rows also add to the counter ``moe.expert_rows``."""
 
     # 256 rows x 128 tokens keeps a phi3-mini-width forward's activations to
     # a few GB beside the weights and a serving KV cache on one 16 GB chip
@@ -191,7 +196,7 @@ class ModelEncoder:
     def __init__(self, cfg, params, tokenizer, max_len: int = 128):
         from repro.models import get_model  # lazy: avoids cycle
         from repro.models import transformer as T
-        from repro.models import layers as L
+        from repro.models.layers import expert_buffer_rows
 
         self.cfg = cfg
         self.params = params
@@ -200,14 +205,18 @@ class ModelEncoder:
         self.dim = cfg.d_model
         self.stats = EncoderStats()
         self.obs = Observability()
+        self._expert_rows = self.obs.registry.counter("moe.expert_rows")
+        self._buffer_rows = (
+            (lambda tokens: cfg.moe_layers * expert_buffer_rows(cfg, tokens))
+            if cfg.moe_layers else None)
 
         def pooled(params, tokens, mask):
             x = params["embed"][tokens]
-            h, _ = T.trunk(params, cfg, x, jnp.arange(tokens.shape[1])[None, :])
+            h, _, rows = T.trunk(params, cfg, x, jnp.arange(tokens.shape[1])[None, :])
             m = mask[..., None].astype(h.dtype)
             s = jnp.sum(h * m, axis=1) / jnp.maximum(jnp.sum(m, axis=1), 1.0)
             n = jnp.linalg.norm(s.astype(jnp.float32), axis=-1, keepdims=True) + 1e-6
-            return (s.astype(jnp.float32) / n)
+            return (s.astype(jnp.float32) / n), rows
 
         self._pooled = jax.jit(pooled)
 
@@ -242,9 +251,13 @@ class ModelEncoder:
             sp.set(texts=n, rows=rows, width=L, tokens=tokens,
                    padded_tokens=rows * L)
             with self.obs.span("encoder.device"):
-                out = np.asarray(self._pooled(self.params, jnp.asarray(toks),
-                                              jnp.asarray(mask)))
-        return out[:n]
+                out, expert_rows = jax.device_get(self._pooled(
+                    self.params, jnp.asarray(toks), jnp.asarray(mask)))
+            if self._buffer_rows is not None:
+                self._expert_rows.inc(int(expert_rows))
+                sp.set(expert_rows=int(expert_rows),
+                       expert_rows_bound=self._buffer_rows(rows * L))
+        return np.asarray(out)[:n]
 
     def encode_one(self, text: str) -> np.ndarray:
         return self.encode([text])[0]

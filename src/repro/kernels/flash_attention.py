@@ -24,7 +24,9 @@ diagonal are skipped. With block_q = block_kv = 512 and D = 128 a step
 holds ~0.7 MB, and the contractions are (512 x 128 x 512).
 
 Both paths upcast q, k and v to fp32 and keep the softmax and the
-accumulation in fp32; the output has q's dtype.
+accumulation in fp32; the output has q's dtype. v may be narrower than q
+and k (latent attention: q/k 192 wide, v 128); the softmax scale defaults
+to 1/sqrt(q/k width).
 """
 from __future__ import annotations
 
@@ -51,25 +53,29 @@ def _tile_bytes(lead: int, rows: int, cols: int, itemsize: int) -> int:
 
 
 def packed_vmem_bytes(slabs: int, group: int, seq: int, head_dim: int,
-                      itemsize: int) -> int:
+                      itemsize: int, v_dim: int = 0) -> int:
     """VMEM a packed step of ``slabs`` slabs holds: the q, k, v and output
     tiles double-buffered in the input dtype, and the fp32 temporaries
-    (upcast q, k, v, scores, probabilities, output)."""
-    rows = group * seq
-    tiles = (2 * _tile_bytes(slabs, rows, head_dim, itemsize)
-             + 2 * _tile_bytes(slabs, seq, head_dim, itemsize))
-    temps = (2 * _tile_bytes(slabs, rows, head_dim, 4)
-             + 2 * _tile_bytes(slabs, seq, head_dim, 4)
+    (upcast q, k, v, scores, probabilities, output). v and the output are
+    ``v_dim`` wide (0: ``head_dim``)."""
+    rows, dv = group * seq, v_dim or head_dim
+    tiles = (_tile_bytes(slabs, rows, head_dim, itemsize)
+             + _tile_bytes(slabs, rows, dv, itemsize)
+             + _tile_bytes(slabs, seq, head_dim, itemsize)
+             + _tile_bytes(slabs, seq, dv, itemsize))
+    temps = (_tile_bytes(slabs, rows, head_dim, 4) + _tile_bytes(slabs, rows, dv, 4)
+             + _tile_bytes(slabs, seq, head_dim, 4) + _tile_bytes(slabs, seq, dv, 4)
              + 2 * _tile_bytes(slabs, rows, seq, 4))
     return 2 * tiles + temps
 
 
 def slabs_per_step(n_slabs: int, group: int, seq: int, head_dim: int,
-                   itemsize: int) -> int:
+                   itemsize: int, v_dim: int = 0) -> int:
     """G for the packed path: the largest power of two that divides
     ``n_slabs`` (= B*Hkv), holds at most ``PACKED_ROWS`` query rows (at
     least one slab) and fits ``VMEM_BUDGET``; 0 where one slab does not fit."""
-    fits = lambda n: packed_vmem_bytes(n, group, seq, head_dim, itemsize) <= VMEM_BUDGET
+    fits = lambda n: packed_vmem_bytes(n, group, seq, head_dim, itemsize,
+                                       v_dim) <= VMEM_BUDGET
     slabs = 1
     while (n_slabs % (2 * slabs) == 0 and 2 * slabs * group * seq <= PACKED_ROWS
            and fits(2 * slabs)):
@@ -79,7 +85,7 @@ def slabs_per_step(n_slabs: int, group: int, seq: int, head_dim: int,
 
 def _packed_kernel(q_ref, k_ref, v_ref, o_ref, *, causal: bool, seq: int,
                    sm_scale: float):
-    # q_ref, o_ref: (G, g*S, D); k_ref, v_ref: (G, S, D)
+    # q_ref: (G, g*S, D); k_ref: (G, S, D); v_ref: (G, S, Dv); o_ref: (G, g*S, Dv)
     q = q_ref[...].astype(jnp.float32)
     k = k_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)
@@ -95,38 +101,41 @@ def _packed_kernel(q_ref, k_ref, v_ref, o_ref, *, causal: bool, seq: int,
     l = jnp.maximum(jnp.sum(p, axis=2, keepdims=True), 1e-30)
     o = jax.lax.dot_general(
         p, v, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    )                                                  # (G, g*S, D)
+    )                                                  # (G, g*S, Dv)
     o_ref[...] = (o / l).astype(o_ref.dtype)
 
 
-def _packed_attention(q, k, v, *, causal: bool, slabs: int, interpret: bool):
+def _packed_attention(q, k, v, *, causal: bool, slabs: int, sm_scale: float,
+                      interpret: bool):
     B, S, Hq, D = q.shape
-    Hkv = k.shape[2]
+    Hkv, Dv = k.shape[2], v.shape[3]
     g = Hq // Hkv
     n = B * Hkv
     qp = q.reshape(B, S, Hkv, g, D).transpose(0, 2, 3, 1, 4).reshape(n, g * S, D)
     kp = k.transpose(0, 2, 1, 3).reshape(n, S, D)
-    vp = v.transpose(0, 2, 1, 3).reshape(n, S, D)
+    vp = v.transpose(0, 2, 1, 3).reshape(n, S, Dv)
     kernel = functools.partial(_packed_kernel, causal=causal, seq=S,
-                               sm_scale=1.0 / (D ** 0.5))
+                               sm_scale=sm_scale)
     q_spec = pl.BlockSpec((slabs, g * S, D), lambda i: (i, 0, 0))
-    kv_spec = pl.BlockSpec((slabs, S, D), lambda i: (i, 0, 0))
+    k_spec = pl.BlockSpec((slabs, S, D), lambda i: (i, 0, 0))
+    v_spec = pl.BlockSpec((slabs, S, Dv), lambda i: (i, 0, 0))
+    o_spec = pl.BlockSpec((slabs, g * S, Dv), lambda i: (i, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid=(n // slabs,),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((n, g * S, D), q.dtype),
+        in_specs=[q_spec, k_spec, v_spec],
+        out_specs=o_spec,
+        out_shape=jax.ShapeDtypeStruct((n, g * S, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(qp, kp, vp)
-    return out.reshape(B, Hkv, g, S, D).transpose(0, 3, 1, 2, 4).reshape(B, S, Hq, D)
+    return out.reshape(B, Hkv, g, S, Dv).transpose(0, 3, 1, 2, 4).reshape(B, S, Hq, Dv)
 
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref,      # (1,1,bq,D), (1,1,bk,D), (1,1,bk,D)
-    o_ref,                    # (1,1,bq,D)
-    acc_ref, m_ref, l_ref,    # scratch: (bq,D) f32, (bq,1) f32, (bq,1) f32
+    q_ref, k_ref, v_ref,      # (1,1,bq,D), (1,1,bk,D), (1,1,bk,Dv)
+    o_ref,                    # (1,1,bq,Dv)
+    acc_ref, m_ref, l_ref,    # scratch: (bq,Dv) f32, (bq,1) f32, (bq,1) f32
     *,
     causal: bool,
     block_q: int,
@@ -153,7 +162,7 @@ def _flash_kernel(
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)           # (bq, D)
         k = k_ref[0, 0].astype(jnp.float32)           # (bk, D)
-        v = v_ref[0, 0].astype(jnp.float32)           # (bk, D)
+        v = v_ref[0, 0].astype(jnp.float32)           # (bk, Dv)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * sm_scale                                   # (bq, bk)
@@ -180,27 +189,29 @@ def _flash_kernel(
 def flash_attention(
     q: jax.Array,  # (B, S, Hq, D)
     k: jax.Array,  # (B, S, Hkv, D)
-    v: jax.Array,  # (B, S, Hkv, D)
+    v: jax.Array,  # (B, S, Hkv, Dv)
     *,
     causal: bool = True,
     block_q: int = DEFAULT_BLOCK_Q,
     block_kv: int = DEFAULT_BLOCK_KV,
+    sm_scale: float | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     B, S, Hq, D = q.shape
-    Hkv = k.shape[2]
+    Hkv, Dv = k.shape[2], v.shape[3]
     group = Hq // Hkv
+    if sm_scale is None:
+        sm_scale = 1.0 / (D ** 0.5)
     if S <= block_q and S <= block_kv:
-        slabs = slabs_per_step(B * Hkv, group, S, D, q.dtype.itemsize)
+        slabs = slabs_per_step(B * Hkv, group, S, D, q.dtype.itemsize, Dv)
         if slabs:
             return _packed_attention(q, k, v, causal=causal, slabs=slabs,
-                                     interpret=interpret)
+                                     sm_scale=sm_scale, interpret=interpret)
     block_q = min(block_q, S)
     block_kv = min(block_kv, S)
     assert S % block_q == 0 and S % block_kv == 0, (S, block_q, block_kv)
     nq = S // block_q
     nkv = S // block_kv
-    sm_scale = 1.0 / (D ** 0.5)
 
     qt = q.transpose(0, 2, 1, 3)  # (B, H, S, D)
     kt = k.transpose(0, 2, 1, 3)
@@ -220,12 +231,12 @@ def flash_attention(
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, block_kv, D), lambda b, h, iq, ik: (b, h // group, ik, 0)),
-            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, iq, ik: (b, h // group, ik, 0)),
+            pl.BlockSpec((1, 1, block_kv, Dv), lambda b, h, iq, ik: (b, h // group, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, S, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, S, Dv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],

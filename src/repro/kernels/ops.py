@@ -30,6 +30,7 @@ from repro.kernels import ref as _ref
 from repro.kernels.browse_scores import browse_scores as _browse
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.decode_attention import decode_attention as _decode
+from repro.kernels.grouped_matmul import grouped_matmul as _gmm
 from repro.kernels.topk_sim import topk_sim as _topk
 from repro.kernels.tree_refresh import tree_refresh as _tree_refresh
 from repro.kernels.rwkv6_scan import rwkv6_scan as _rwkv6
@@ -61,18 +62,33 @@ def resolve_impl(impl: Optional[str] = None,
     return impl
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "impl", "block_q", "block_kv"))
-def attention(q, k, v, *, causal=True, impl="reference", block_q=512, block_kv=512):
+@functools.partial(jax.jit, static_argnames=("causal", "impl", "block_q", "block_kv",
+                                             "sm_scale"))
+def attention(q, k, v, *, causal=True, impl="reference", block_q=512, block_kv=512,
+              sm_scale=None):
+    """q, k (B, S, H, Dk), v (B, S, H, Dv); ``sm_scale`` None = Dk^-1/2."""
     _check(impl)
     if impl == "reference":
-        return _ref.attention_ref(q, k, v, causal=causal)
+        return _ref.attention_ref(q, k, v, causal=causal, sm_scale=sm_scale)
     return _flash(
         q, k, v,
         causal=causal,
         block_q=block_q,
         block_kv=block_kv,
+        sm_scale=sm_scale,
         interpret=(impl == "pallas_interpret"),
     )
+
+
+@functools.partial(jax.jit, static_argnames=("impl",))
+def grouped_matmul(lhs, rhs, group_sizes, *, impl="reference"):
+    """(m, K) rows grouped by ``group_sizes`` times their group's (K, N)
+    matrix of ``rhs`` (G, K, N) -> (m, N) in lhs's dtype; rows past
+    ``sum(group_sizes)`` are undefined."""
+    _check(impl)
+    if impl == "reference":
+        return _ref.grouped_matmul_ref(lhs, rhs, group_sizes)
+    return _gmm(lhs, rhs, group_sizes, interpret=(impl == "pallas_interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "block_kv"))

@@ -21,6 +21,7 @@ def attention_ref(
     v: jax.Array,  # (B, S, Hkv, D)
     *,
     causal: bool = True,
+    sm_scale=None,
 ) -> jax.Array:
     B, S, Hq, D = q.shape
     Hkv = k.shape[2]
@@ -28,7 +29,8 @@ def attention_ref(
     if group > 1:
         k = jnp.repeat(k, group, axis=2)
         v = jnp.repeat(v, group, axis=2)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    scale = (1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32)) if sm_scale is None
+             else sm_scale)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
     logits = logits * scale
     if causal:
@@ -37,6 +39,14 @@ def attention_ref(
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def grouped_matmul_ref(lhs: jax.Array, rhs: jax.Array,
+                       group_sizes: jax.Array) -> jax.Array:
+    """lhs (m, K) rows grouped by group_sizes, each times rhs[g] (K, N);
+    rows past the groups are zero."""
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
+                              preferred_element_type=jnp.float32).astype(lhs.dtype)
 
 
 def cross_attention_ref(

@@ -219,20 +219,22 @@ class ServeEngine:
         # The admission merge is a masked select, not a gather + scatter:
         # with the old cache donated it writes in place and needs no
         # slot-sized temporaries, and one compile serves any slot count.
-        num_layers = model.cfg.num_layers
-
+        # Each cache leaf's lane axis is the one whose size follows the
+        # batch in the model's own cache shapes (a stack of any depth, or
+        # several stacks, merges alike); a leaf without one is kept.
         def merge(old_cache, new_cache, take):
-            def rows(old, new):
-                if old.ndim >= 2 and old.shape[0] == num_layers \
-                        and old.shape[1] == max_batch:
-                    lead = (1, max_batch)
-                elif old.ndim >= 1 and old.shape[0] == max_batch:
-                    lead = (max_batch,)
-                else:
-                    return old
-                m = take.reshape(lead + (1,) * (old.ndim - len(lead)))
-                return jnp.where(m, new, old)
-            return jax.tree.map(rows, old_cache, new_cache)
+            one, two = (jax.tree.leaves(model.cache_specs(b, max_len)) for b in (1, 2))
+            lane_axes = [next((i for i, (a, b) in enumerate(zip(s1.shape, s2.shape))
+                               if a != b), None) for s1, s2 in zip(one, two)]
+            olds, tree = jax.tree.flatten(old_cache)
+            out = []
+            for ax, old, new in zip(lane_axes, olds, jax.tree.leaves(new_cache)):
+                if ax is not None:
+                    shape = [1] * old.ndim
+                    shape[ax] = max_batch
+                    old = jnp.where(take.reshape(shape), new, old)
+                out.append(old)
+            return jax.tree.unflatten(tree, out)
 
         self._merge = jax.jit(merge, donate_argnums=(0,))
 

@@ -78,6 +78,58 @@ def test_flash_attention_packed_noncausal(rng, B, S, Hq, Hkv, D, dtype):
                                atol=ATOL[dtype], rtol=1e-2)
 
 
+@pytest.mark.parametrize("B,S,H,Dk,Dv,bq,bk", [
+    (2, 32, 4, 24, 16, 512, 512),    # packed: latent attention's shape at a tiny size
+    (4, 32, 16, 192, 128, 512, 512),  # packed: DeepSeek-V2's q/k 192, v 128
+    (2, 256, 4, 24, 16, 128, 128),   # tiled
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_v_narrower_with_a_scale(rng, B, S, H, Dk, Dv, bq, bk, dtype):
+    """v of its own width and a softmax scale passed in, on both paths."""
+    q = _mk(rng, (B, S, H, Dk), dtype)
+    k = _mk(rng, (B, S, H, Dk), dtype)
+    v = _mk(rng, (B, S, H, Dv), dtype)
+    scale = 1.5896 / Dk ** 0.5
+    assert len(_grid(q, k, v, block_q=bq, block_kv=bk, sm_scale=scale)) == (
+        1 if S <= bq else 4)
+    o1 = ops.attention(q, k, v, impl="reference", sm_scale=scale)
+    o2 = ops.attention(q, k, v, impl="pallas_interpret", block_q=bq, block_kv=bk,
+                       sm_scale=scale)
+    assert o2.shape == (B, S, H, Dv)
+    np.testing.assert_allclose(np.asarray(o1, np.float32), np.asarray(o2, np.float32),
+                               atol=ATOL[dtype], rtol=1e-2)
+
+
+def test_flash_attention_default_scale_is_the_head_width():
+    """With Dk == Dv and no scale the call is the one it always was."""
+    q = jax.ShapeDtypeStruct((2, 32, 4, 96), jnp.bfloat16)
+    plain = jax.make_jaxpr(lambda q, k, v: fa.flash_attention(q, k, v, interpret=True))
+    named = jax.make_jaxpr(lambda q, k, v: fa.flash_attention(
+        q, k, v, sm_scale=1.0 / 96 ** 0.5, interpret=True))
+    assert str(plain(q, q, q)) == str(named(q, q, q))
+
+
+@pytest.mark.parametrize("sizes", [[300, 0, 17, 195], [0, 0, 0, 0], [512, 0, 0, 0],
+                                   [1, 2, 3, 4]])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_grouped_matmul(rng, sizes, dtype):
+    """Rows grouped by expert against each expert's matrix; rows past the
+    groups are not the kernel's to write and are not compared."""
+    m, K, N = 512, 64, 96
+    lhs = _mk(rng, (m, K), dtype)
+    rhs = _mk(rng, (4, K, N), dtype, scale=K ** -0.5)
+    gs = jnp.asarray(sizes, jnp.int32)
+    want = np.concatenate([np.asarray(lhs[a:a + n], np.float32) @ np.asarray(rhs[g], np.float32)
+                           for g, (a, n) in enumerate(zip(np.cumsum([0] + sizes), sizes))]
+                          + [np.zeros((0, N), np.float32)])
+    n = sum(sizes)
+    for impl in ("reference", "pallas_interpret"):
+        got = ops.grouped_matmul(lhs, rhs, gs, impl=impl)
+        assert got.shape == (m, N) and got.dtype == dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32)[:n], want,
+                                   atol=ATOL[dtype] * 4, rtol=1e-2)
+
+
 ENCODER = [(rows, width, 32, 32, 96) for rows in (8, 16, 32, 64, 128, 256)
            for width in (16, 32, 64, 128)]
 GRANITE = [(rows, width, 32, 8, 128) for rows in (1, 8, 64, 256)

@@ -73,6 +73,7 @@ def test_full_config_param_count(arch):
         "llama3-8b": (6.5e9, 9e9),
         "granite-3-8b": (6.5e9, 10e9),
         "pixtral-12b": (10e9, 14e9),
+        "deepseek-v2-lite": (15.0e9, 16.5e9),
     }[cfg.name]
     assert expected[0] <= n <= expected[1], (cfg.name, f"{n:,}")
     if cfg.family == "moe":

@@ -97,3 +97,42 @@ def test_decode_attention_compiles(one_chip):
     cache = ((8, MAX_LEN, HEADS, HEAD_DIM), BF16)
     _compile(lambda q, k, v, n: ops.decode_attention(q, k, v, n, impl="pallas"),
              one_chip, ((8, HEADS, HEAD_DIM), BF16), cache, cache, ((8,), I32))
+
+
+# DeepSeek-V2-Lite: latent attention decompressed to 16 heads of q/k 192
+# and v 128, and the expert layer's grouped matmuls (16 experts held, d
+# 2048, expert width 1408) at an encoder forward's buffer (256 x 32 tokens,
+# 6 rows a token)
+@pytest.mark.parametrize("b,s", [pytest.param(256, 32, id="256x32"),
+                                 pytest.param(256, 128, id="256x128"),
+                                 pytest.param(8, 1024, id="1024")])
+def test_latent_attention_flash_compiles(one_chip, b, s):
+    qk, v = ((b, s, 16, 192), BF16), ((b, s, 16, 128), BF16)
+    _compile(lambda q, k, v: ops.attention(q, k, v, impl="pallas",
+                                           sm_scale=1.5896 / 192 ** 0.5),
+             one_chip, qk, qk, v)
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+def test_grouped_matmul_compiles(one_chip, k, n):
+    _compile(lambda x, w, g: ops.grouped_matmul(x, w, g, impl="pallas"),
+             one_chip, ((49152, k), BF16), ((16, k, n), BF16), ((16,), I32))
+
+
+def test_dropless_expert_layer_compiles(one_chip):
+    """One expert layer of DeepSeek-V2-Lite with 16 of 64 experts held, at
+    an encoder forward of 256 x 32 tokens: the switch over its three
+    buffer sizes, each with its grouped matmuls."""
+    from repro.configs import get_config
+    from repro.models import layers as L
+
+    cfg = get_config("deepseek_v2_lite").replace(experts_held=16, attention_impl="pallas")
+    assert len(L.expert_buffer_tiers(cfg, 256 * 32)) == 3
+    p = jax.eval_shape(lambda: L.moe_init(jax.random.key(0), cfg, BF16))
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in jax.tree.leaves(p)]
+    x = jax.ShapeDtypeStruct((256, 32, 2048), BF16, sharding=one_chip)
+    tree = jax.tree.structure(p)
+    text = jax.jit(lambda x, *a: L.moe_block(jax.tree.unflatten(tree, a), x, cfg)[0]
+                   ).lower(x, *args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 9
